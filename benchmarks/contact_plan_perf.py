@@ -30,6 +30,7 @@ from repro.orbit.constellation import WalkerStar, satellite_elements
 from repro.orbit.groundstations import gs_ecef
 from repro.orbit.visibility import (elevation_mask_series,
                                     windows_from_bool_tensor)
+from repro.launch.compile_cache import use_compile_cache
 
 SCALES = {
     # name: (clusters, sats/cluster, ground stations, horizon_s, dt_s)
@@ -137,6 +138,7 @@ def bench_scale(name: str, n_queries: int) -> dict:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--scales", nargs="+", default=list(SCALES),
                     choices=list(SCALES))

@@ -54,6 +54,7 @@ from repro.core.spaceify import FedAvgSat, FLConfig
 from repro.data.synthetic import make_federated_dataset
 from repro.sim.faults import FaultConfig, StormConfig, StormEvent
 from repro.sim.hardware import SMALLSAT_SBAND
+from repro.launch.compile_cache import use_compile_cache
 
 N_GS = 3
 N_PER_CLIENT = 32
@@ -129,6 +130,7 @@ def run_point(name, plan, ds, cfg):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_degradation.json")
     ap.add_argument("--smoke", action="store_true",

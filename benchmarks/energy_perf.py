@@ -41,6 +41,7 @@ from repro.orbit.eclipse import eclipse_series
 from repro.sim.energy import EnergyConfig, EnergySim
 from repro.sim.energy_ref import EnergySimRef
 from repro.sim.hardware import FLYCUBE
+from repro.launch.compile_cache import use_compile_cache
 
 SCALES = {
     # name: (clusters, sats/cluster, horizon_s, eclipse_dt_s)
@@ -162,6 +163,7 @@ def bench_scale(name: str, smoke: bool) -> dict:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--scales", nargs="+", default=None,
                     choices=list(SCALES))

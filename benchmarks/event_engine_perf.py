@@ -36,6 +36,7 @@ from repro.sim.energy import EnergyConfig, EnergySim
 from repro.sim.events import WorldTimeline
 from repro.sim.faults import FaultConfig, FaultSim
 from repro.sim.hardware import FLYCUBE
+from repro.launch.compile_cache import use_compile_cache
 
 SCALES = {
     # name: (clusters, sats/cluster, ground stations, horizon_s, dt_s)
@@ -158,6 +159,7 @@ def bench_scale(name: str, smoke: bool) -> dict:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--scales", nargs="+", default=None,
                     choices=list(SCALES))

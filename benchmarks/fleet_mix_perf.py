@@ -39,6 +39,7 @@ from repro.core.contact_plan import build_contact_plan
 from repro.core.spaceify import FedAvgSat, FedProxSat, FLConfig
 from repro.data.synthetic import make_federated_dataset
 from repro.sim.hardware import FLYCUBE, SMALLSAT_SBAND, FleetProfile
+from repro.launch.compile_cache import use_compile_cache
 
 ALGOS = {"fedavg": FedAvgSat, "fedprox": FedProxSat}
 C, SPC = 2, 5                       # the paper's 2x5 constellation
@@ -109,6 +110,7 @@ def run_sweep_point(name, cls, plan, ds, cfg, fleet):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_fleet_mix.json")
     ap.add_argument("--smoke", action="store_true",

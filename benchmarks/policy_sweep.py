@@ -55,6 +55,7 @@ from repro.core.spaceify import EnergyConfig, FedAvgSat, FLConfig
 from repro.data.synthetic import make_federated_dataset
 from repro.sim.faults import FaultConfig, StormConfig, StormEvent
 from repro.sim.hardware import SMALLSAT_SBAND
+from repro.launch.compile_cache import use_compile_cache
 
 N_GS = 3
 N_PER_CLIENT = 32
@@ -143,6 +144,7 @@ def run_point(name, plan, ds, cfg):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_policy.json")
     ap.add_argument("--smoke", action="store_true",

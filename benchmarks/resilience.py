@@ -51,6 +51,7 @@ from repro.data.synthetic import make_federated_dataset
 from repro.sim.energy import EnergyConfig
 from repro.sim.faults import EnergyDrainAttack, FaultConfig, PoisonAttack
 from repro.sim.hardware import SMALLSAT_SBAND
+from repro.launch.compile_cache import use_compile_cache
 
 N_GS = 3
 N_PER_CLIENT = 32
@@ -162,6 +163,7 @@ def run_point(name, plan, ds, cfg):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_resilience.json")
     ap.add_argument("--smoke", action="store_true",

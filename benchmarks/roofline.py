@@ -26,6 +26,7 @@ import pathlib
 import sys
 
 from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.compile_cache import use_compile_cache
 
 DRYRUN_DIR = pathlib.Path("experiments/dryrun")
 
@@ -104,6 +105,7 @@ def self_check() -> list:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--mesh", default="single")
     ap.add_argument("--tag", default=None)

@@ -44,6 +44,7 @@ from repro.core.spaceify import FedAvgSat, FedProxSat, FLConfig
 from repro.data.synthetic import make_federated_dataset
 from repro.sim.hardware import SMALLSAT_SBAND
 import repro.models.small as small_models
+from repro.launch.compile_cache import use_compile_cache
 
 SCALES = {
     # name: (clusters, sats/cluster, horizon_days, sweep gs counts)
@@ -192,6 +193,7 @@ def quant_kernel_in_sim_check(scale, plan, ds):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scales", nargs="+", default=None,
                     choices=list(SCALES))

@@ -6,16 +6,18 @@
 
 Each section prints CSV rows; the roofline section reads the dry-run
 artifacts (run `python -m repro.launch.dryrun` first for fresh numbers).
-Without ``--smoke`` a failing section is reported and the harness keeps
-going (exploratory use); with it, any section error — or a section
-producing no rows — exits nonzero so CI catches a bit-rotted benchmark.
+A failing section is reported and the remaining sections still run, but
+the harness then exits nonzero. ``--smoke`` also fails a section that
+produces no rows, so CI catches a bit-rotted benchmark.
 """
 from __future__ import annotations
 
 import argparse
 import time
+import traceback
 
 from benchmarks.common import print_rows
+from repro.launch.compile_cache import use_compile_cache
 
 SECTIONS = [
     ("power", "Table 2: FLyCube power modes & added OAP",
@@ -42,13 +44,14 @@ SECTIONS = [
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("sections", nargs="*",
                     choices=[k for k, _, _ in SECTIONS],
                     help="subset of sections (default: all)")
     ap.add_argument("--smoke", action="store_true",
-                    help="exit nonzero if any section errors or is empty "
-                         "(CI gate); roofline's empty dry-run is tolerated "
+                    help="also exit nonzero if a section is empty (CI "
+                         "gate); roofline's empty dry-run is tolerated "
                          "via its own self-check")
     args = ap.parse_args()
     want = set(args.sections)
@@ -61,7 +64,8 @@ def main() -> None:
         try:
             mod = __import__(modname, fromlist=["run"])
             rows = mod.run(fast=True)
-        except Exception as e:  # keep the harness going, report the failure
+        except Exception as e:  # run the other sections, then exit nonzero
+            traceback.print_exc()
             print(f"\n## {title}\nERROR: {type(e).__name__}: {e}")
             failures.append(f"{key}: {type(e).__name__}: {e}")
             continue
@@ -71,8 +75,8 @@ def main() -> None:
         if args.smoke and not rows and key != "roofline":
             failures.append(f"{key}: produced no rows")
     print(f"\ntotal: {time.time() - t0:.0f}s")
-    if args.smoke and failures:
-        raise SystemExit("smoke failures:\n  " + "\n  ".join(failures))
+    if failures:
+        raise SystemExit("failed sections:\n  " + "\n  ".join(failures))
 
 
 if __name__ == "__main__":

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
 from repro.launch import specs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 
 
@@ -52,6 +53,7 @@ def generate(cfg, params, prompts, gen_len, temperature=0.0, seed=0):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x22b")
     ap.add_argument("--reduced", action="store_true")

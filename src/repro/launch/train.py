@@ -22,14 +22,16 @@ import json
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_pytree
 from repro.configs import get_config, get_smoke_config
 from repro.core import hierarchy as H
 from repro.data.tokens import synthetic_lm_batches
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_local_mesh
 from repro.optim.optimizers import AdamWConfig
+from repro.sharding.partition import named
 from repro.train import steps as ST
 
 
@@ -41,7 +43,56 @@ def build_cfg(args):
     return dataclasses.replace(cfg, **over)
 
 
-def main():
+def hfl_mesh(n_clusters: int):
+    """(pod, data, model) mesh over every device: one pod per cluster
+    where the clusters divide the devices, the rest of each pod on
+    ``model``. On one device it is (1, 1, 1) and nothing is sharded."""
+    n_dev = jax.device_count()
+    pod = n_clusters if n_dev % n_clusters == 0 else 1
+    return make_local_mesh(data=1, model=n_dev // pod, pod=pod)
+
+
+def hfl_programs(cfg, opt_cfg, mesh, n_clusters: int):
+    """The hierarchical trainer's tier-1 programs on ``mesh``:
+    ``(init, local, place_batch)``. ``init(key)`` creates the per-cluster
+    state already sharded by ``hfl_state_specs``; ``local`` keeps it in
+    that sharding and donates its input; ``place_batch(batches)`` stacks
+    one host batch per cluster into (C, local_b, ...) and places it on
+    the clusters' pods."""
+    state_sh = named(mesh, H.hfl_state_specs(cfg, mesh))
+    init = jax.jit(lambda key: H.init_hfl_state(key, cfg, n_clusters),
+                   out_shardings=state_sh)
+    local = jax.jit(H.make_hfl_local_step(cfg, opt_cfg),
+                    in_shardings=(state_sh, None),
+                    out_shardings=(state_sh, None), donate_argnums=0)
+
+    def place_batch(batches):
+        hb = jax.tree.map(lambda *xs: np.stack(xs), *batches)
+        return jax.device_put(
+            hb, named(mesh, H.hfl_batch_specs(cfg, mesh, hb)))
+
+    return init, local, place_batch
+
+
+def hfl_sync(cfg, mesh, quant_bits: int = 0, cluster_weights=None):
+    """The tier-2 cluster sync, jitted to keep (and donate) the state in
+    its ``hfl_state_specs`` sharding on ``mesh``."""
+    state_sh = named(mesh, H.hfl_state_specs(cfg, mesh))
+    return jax.jit(H.make_cluster_sync(cfg, quant_bits=quant_bits,
+                                       cluster_weights=cluster_weights),
+                   in_shardings=(state_sh,), out_shardings=state_sh,
+                   donate_argnums=0)
+
+
+def cluster_streams(cfg, args):
+    """One synthetic token stream per cluster, each from its own seed, so
+    every cluster sees its own (non-IID) data."""
+    return [synthetic_lm_batches(cfg.vocab, args.batch, args.seq, args.steps,
+                                 seed=args.seed + 17 * c)
+            for c in range(args.clusters)]
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b")
     ap.add_argument("--reduced", action="store_true",
@@ -80,8 +131,12 @@ def main():
                          "simulated fleet and weights the tier-2 cluster "
                          "sync accordingly; empty keeps the uniform "
                          "(bitwise pre-policy) sync")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main():
+    use_compile_cache()
+    args = parse_args()
     cfg = build_cfg(args)
     opt_cfg = AdamWConfig(lr=args.lr, warmup_steps=args.warmup)
     key = jax.random.PRNGKey(args.seed)
@@ -89,13 +144,14 @@ def main():
 
     if args.hfl:
         nc = args.clusters
-        state = H.init_hfl_state(key, cfg, nc)
-        local = jax.jit(H.make_hfl_local_step(cfg, opt_cfg), donate_argnums=0)
+        mesh = hfl_mesh(nc)
         cluster_w = None
         if args.policy and args.sync_every != "orbit":
             raise SystemExit("--policy needs --hfl --sync-every orbit (the "
                              "policy budgets are derived from the simulated "
                              "fleet and ISL schedule)")
+        init, local, place_batch = hfl_programs(cfg, opt_cfg, mesh, nc)
+        state = init(key)
         if args.sync_every == "orbit":
             from repro.core.contact_plan import build_contact_plan
             from repro.core.quantize import transmit_bytes
@@ -160,17 +216,10 @@ def main():
                           f"{verdict}")
         else:
             h_sync = int(args.sync_every)
-        sync = jax.jit(H.make_cluster_sync(cfg, quant_bits=args.quant_bits,
-                                           cluster_weights=cluster_w),
-                       donate_argnums=0)
-        # each cluster sees its own (non-IID) stream
-        streams = [synthetic_lm_batches(cfg.vocab, args.batch, args.seq,
-                                        args.steps, seed=args.seed + 17 * c)
-                   for c in range(nc)]
+        sync = hfl_sync(cfg, mesh, args.quant_bits, cluster_w)
+        streams = cluster_streams(cfg, args)
         for i in range(args.steps):
-            bs = [next(s) for s in streams]
-            hb = jax.tree.map(lambda *xs: jnp.stack(xs), *bs)
-            state, m = local(state, hb)
+            state, m = local(state, place_batch([next(s) for s in streams]))
             if (i + 1) % h_sync == 0:
                 state = sync(state)
             if i % args.log_every == 0 or i == args.steps - 1:

@@ -15,7 +15,7 @@ import numpy as np
 from repro.core.autoflsat import AutoFLSat
 from repro.core.contact_plan import ContactPlan, build_contact_plan
 from repro.core.spaceify import ALGORITHMS, FLConfig, RoundRecord
-from repro.data.synthetic import make_federated_dataset
+from repro.data.synthetic import FedDataset, make_federated_dataset
 from repro.sim.hardware import FLYCUBE, FleetProfile, HardwareProfile
 
 
@@ -204,7 +204,11 @@ class SimResult:
 
 class FLySTacK:
     def __init__(self, cfg: SimConfig, hw: HardwareProfile = FLYCUBE,
-                 plan: Optional[ContactPlan] = None):
+                 plan: Optional[ContactPlan] = None,
+                 dataset: Optional[FedDataset] = None):
+        """``plan`` / ``dataset``: a contact plan or federated dataset
+        already built for this ``cfg``'s constellation, shared between
+        experiments instead of being built again."""
         self.cfg = cfg
         K = cfg.n_clusters * cfg.sats_per_cluster
         # SimConfig.fleet (heterogeneous per-satellite hardware) wins over
@@ -216,9 +220,10 @@ class FLySTacK:
             cfg.n_clusters, cfg.sats_per_cluster, cfg.n_ground_stations,
             horizon_s=cfg.horizon_days * 86_400, dt_s=cfg.dt_s,
             min_elev_deg=cfg.min_elev_deg, with_isl_pairs=needs_isl)
-        self.dataset = make_federated_dataset(
-            cfg.dataset, n_clients=cfg.n_clusters * cfg.sats_per_cluster,
-            n_per_client=cfg.n_per_client, alpha=cfg.alpha, seed=cfg.seed)
+        self.dataset = dataset if dataset is not None else \
+            make_federated_dataset(
+                cfg.dataset, n_clients=K, n_per_client=cfg.n_per_client,
+                alpha=cfg.alpha, seed=cfg.seed)
 
     def run(self) -> SimResult:
         cfg = self.cfg

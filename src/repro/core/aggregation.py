@@ -49,6 +49,8 @@ from typing import Iterable, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 
 @jax.jit
 def _weighted_average_impl(stacked_params, w):
@@ -228,14 +230,14 @@ class NormClipAggregator(RobustAggregator):
     def aggregate(self, stacked_params, weights, reference, mode="auto"):
         w = jnp.asarray(weights, jnp.float32)
         valid = w > 0
-        m = int(valid.sum())
+        m = int(obs.sync(valid.sum()))
         norms = _row_delta_norms(stacked_params, reference)
         srt = jnp.sort(jnp.where(valid, norms, jnp.inf))
         med = 0.5 * (srt[(m - 1) // 2] + srt[m // 2])
         limit = self.multiplier * med
         factor = jnp.where(
             valid, jnp.minimum(1.0, limit / jnp.maximum(norms, 1e-12)), 0.0)
-        n_att = int(jnp.sum(valid & (norms > limit)))
+        n_att = int(obs.sync(jnp.sum(valid & (norms > limit))))
 
         def clipped(leaf, r):
             fb = factor.reshape((-1,) + (1,) * (leaf.ndim - 1))
@@ -280,7 +282,7 @@ class TrimmedMeanAggregator(RobustAggregator):
         w = jnp.asarray(weights, jnp.float32)
         valid = w > 0
         k = int(valid.shape[0])
-        m = int(valid.sum())
+        m = int(obs.sync(valid.sum()))
         lo = min(int(self.trim * m), max((m - 1) // 2, 0))
         kept = m - 2 * lo
         rw = jnp.zeros((k,), jnp.float32).at[lo:m - lo].set(1.0 / kept)
@@ -299,7 +301,7 @@ class MedianAggregator(RobustAggregator):
         w = jnp.asarray(weights, jnp.float32)
         valid = w > 0
         k = int(valid.shape[0])
-        m = int(valid.sum())
+        m = int(obs.sync(valid.sum()))
         mid_lo, mid_hi = (m - 1) // 2, m // 2
         rw = jnp.zeros((k,), jnp.float32)
         rw = rw.at[mid_lo].add(0.5).at[mid_hi].add(0.5)
@@ -319,7 +321,7 @@ class KrumAggregator(RobustAggregator):
     def aggregate(self, stacked_params, weights, reference, mode="auto"):
         w = jnp.asarray(weights, jnp.float32)
         valid = w > 0
-        m = int(valid.sum())
+        m = int(obs.sync(valid.sum()))
         rows = jnp.where(valid[:, None], _flatten_rows(stacked_params), 0.0)
         sq = (rows * rows).sum(1)
         d2 = jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * rows @ rows.T, 0.0)
@@ -330,7 +332,8 @@ class KrumAggregator(RobustAggregator):
         srt = jnp.sort(d2, axis=1)
         score = srt[:, :n_nb].sum(1) if n_nb > 0 \
             else jnp.zeros((d2.shape[0],), jnp.float32)
-        winner = int(jnp.argmin(jnp.where(valid, score, jnp.inf)))
+        winner = int(obs.sync(jnp.argmin(jnp.where(valid, score,
+                                                    jnp.inf))))
         out = jax.tree.map(lambda leaf: leaf[winner], stacked_params)
         return out, max(m - 1, 0)
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.aggregation import segment_mean, segment_weighted_mean
 from repro.core.client import local_sgd_clients
 from repro.core.contact_plan import ContactPlan
@@ -59,8 +60,11 @@ class AutoFLSat(SpaceifiedFL):
 
     def __init__(self, plan: ContactPlan, hw, dataset, cfg: FLConfig,
                  epochs_mode: str = "fixed"):
-        super().__init__(plan, hw, dataset, cfg)
         self.epochs_mode = epochs_mode       # "fixed" | "auto"
+        super().__init__(plan, hw, dataset, cfg)
+
+    def _init(self, plan: ContactPlan, hw, dataset, cfg: FLConfig):
+        super()._init(plan, hw, dataset, cfg)
         C = plan.constellation.n_clusters
         self.n_clusters = C
         # per-cluster models start from the seeded w_0
@@ -198,7 +202,8 @@ class AutoFLSat(SpaceifiedFL):
     # ------------------------------------------------------------------
     def run_round(self, r, t):
         cfg, plan = self.cfg, self.plan
-        sched = self.inter_sl_scheduler(t)
+        with obs.span("fl.select"):
+            sched = self.inter_sl_scheduler(t)
         if sched is None:
             return None
         e = sched.epochs
@@ -257,22 +262,23 @@ class AutoFLSat(SpaceifiedFL):
         # as ONE (C*spc)-wide vmapped dispatch + a segment-wise cluster
         # aggregation — no per-cluster Python loop, so the trainer compiles
         # once for the whole constellation.
-        ks = jax.random.split(self.key, K + 1)
-        self.key = ks[0]
-        keys = ks[1:]                        # sat (c, s) gets row c*spc + s
-        bcast = self.cluster_params
-        if cfg.quant_bits:                   # every transmitted model is
-            bcast = quantize_roundtrip_stacked(bcast, cfg.quant_bits)
-        stacked = jax.tree.map(
-            lambda p: jnp.broadcast_to(
-                p[:, None], (C, spc) + p.shape[1:]).reshape(
-                    (K,) + p.shape[1:]), bcast)
-        trained = local_sgd_clients(
-            cfg.model, stacked, self.ds.x, self.ds.y,
-            keys, ep_k if ep_k is not None else e,
-            cfg.batch_size, cfg.lr)
-        if cfg.quant_bits:                   # member -> cluster-head return
-            trained = quantize_roundtrip_stacked(trained, cfg.quant_bits)
+        with obs.span("fl.train"):
+            ks = jax.random.split(self.key, K + 1)
+            self.key = ks[0]
+            keys = ks[1:]                    # sat (c, s) gets row c*spc + s
+            bcast = self.cluster_params
+            if cfg.quant_bits:               # every transmitted model is
+                bcast = quantize_roundtrip_stacked(bcast, cfg.quant_bits)
+            stacked = jax.tree.map(
+                lambda p: jnp.broadcast_to(
+                    p[:, None], (C, spc) + p.shape[1:]).reshape(
+                        (K,) + p.shape[1:]), bcast)
+            trained = local_sgd_clients(
+                cfg.model, stacked, self.ds.x, self.ds.y,
+                keys, ep_k if ep_k is not None else e,
+                cfg.batch_size, cfg.lr)
+            if cfg.quant_bits:               # member -> cluster-head return
+                trained = quantize_roundtrip_stacked(trained, cfg.quant_bits)
 
         # silent payload faults: member k's trained model crosses the
         # intra-cluster ISL to its cluster head at done_k[k]; the delivery
@@ -315,27 +321,30 @@ class AutoFLSat(SpaceifiedFL):
 
         # tier 2: all-to-all exchange -> constellation-wide model (the
         # exchanged cluster models cross ISLs quantized when quant_bits>0)
-        if ok is None:
-            stacked_clusters = segment_mean(trained, C)
-            self.global_params, n_clip = self._aggregate(
-                stacked_clusters, np.full(C, float(spc)))
-            self.cluster_params = jax.tree.map(
-                lambda g: jnp.broadcast_to(g, (C,) + g.shape),
-                self.global_params)
-        else:
-            w = ok.astype(np.float64)
-            seg_w = w.reshape(C, spc).sum(1)   # eligible sats per cluster
-            if seg_w.sum() > 0:
-                stacked_clusters = segment_weighted_mean(
-                    trained, jnp.asarray(w, jnp.float32), C)
-                # clusters with no eligible members carry zero tier-2 weight
+        with obs.span("fl.aggregate"):
+            if ok is None:
+                stacked_clusters = segment_mean(trained, C)
                 self.global_params, n_clip = self._aggregate(
-                    stacked_clusters, seg_w)
+                    stacked_clusters, np.full(C, float(spc)))
                 self.cluster_params = jax.tree.map(
                     lambda g: jnp.broadcast_to(g, (C,) + g.shape),
                     self.global_params)
-            # else: the whole fleet is below the floor — models unchanged,
-            # the round still advances time (the exchange slots were spent)
+            else:
+                w = ok.astype(np.float64)
+                seg_w = w.reshape(C, spc).sum(1)   # eligible sats per cluster
+                if seg_w.sum() > 0:
+                    stacked_clusters = segment_weighted_mean(
+                        trained, jnp.asarray(w, jnp.float32), C)
+                    # clusters with no eligible members carry zero tier-2
+                    # weight
+                    self.global_params, n_clip = self._aggregate(
+                        stacked_clusters, seg_w)
+                    self.cluster_params = jax.tree.map(
+                        lambda g: jnp.broadcast_to(g, (C,) + g.shape),
+                        self.global_params)
+                # else: the whole fleet is below the floor — models
+                # unchanged, the round still advances time (the exchange
+                # slots were spent)
 
         # timing: training overlaps the exchange chain; the round ends when
         # both the last pairwise pass and local training are done. Each
@@ -359,10 +368,12 @@ class AutoFLSat(SpaceifiedFL):
         # fold stale straggler deltas whose delivery landed by this
         # round's end (FedBuff-style staleness discount), then refresh
         # the per-cluster broadcast copies of the patched global model
-        if self._carried and self._fold_carried(t_round_end, r):
-            self.cluster_params = jax.tree.map(
-                lambda g: jnp.broadcast_to(g, (C,) + g.shape),
-                self.global_params)
+        if self._carried:
+            with obs.span("fl.aggregate"):
+                if self._fold_carried(t_round_end, r):
+                    self.cluster_params = jax.tree.map(
+                        lambda g: jnp.broadcast_to(g, (C,) + g.shape),
+                        self.global_params)
         K = plan.constellation.n_sats
         participants = list(range(K))
         wh, skipped = 0.0, 0

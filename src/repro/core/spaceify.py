@@ -86,6 +86,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.aggregation import (apply_buffered_deltas,
                                     make_robust_aggregator,
                                     quantized_weighted_average,
@@ -341,6 +342,13 @@ class SpaceifiedFL:
     name = "base"
 
     def __init__(self, plan: ContactPlan, hw, dataset, cfg: FLConfig):
+        # the engine's spans and counters (repro.obs): its construction
+        # here, its run() through obs.traced_run
+        self.trace = obs.RunTrace(cfg.seed, self.name)
+        with obs.recording(self.trace), obs.span("fl.init"):
+            self._init(plan, hw, dataset, cfg)
+
+    def _init(self, plan: ContactPlan, hw, dataset, cfg: FLConfig):
         # hw: HardwareProfile (uniform fleet), FleetProfile, or a
         # length-K profile sequence — timing always reads the fleet
         # arrays; self.hw stays the scalar primary profile for compat.
@@ -578,29 +586,32 @@ class SpaceifiedFL:
         they vanish from the aggregate; the dispatch shape never changes,
         so the trainer compiles once per configuration. Returns
         (stacked trained params (W, ...), aggregation weights (W,))."""
-        cfg = self.cfg
-        W, m = cfg.clients_per_round, len(sel)
-        ks = jax.random.split(self.key, m + 1)
-        self.key = ks[0]
-        keys = np.empty((W,) + ks.shape[1:], dtype=np.asarray(ks).dtype)
-        keys[:m] = np.asarray(ks[1:])
-        keys[m:] = keys[0]
-        idx = np.zeros(W, np.int64)
-        idx[:m] = sel
-        ep = np.ones(W, np.int32)
-        ep[:m] = epochs
-        tx_global = self._tx_global()
-        stacked = jax.tree.map(
-            lambda p: jnp.broadcast_to(p, (W,) + p.shape), tx_global)
-        gather = jnp.asarray(idx)
-        trained = local_sgd_clients(
-            cfg.model, stacked, self.ds.x[gather], self.ds.y[gather],
-            jnp.asarray(keys), ep, cfg.batch_size, cfg.lr,
-            mu=cfg.prox_mu if prox else 0.0,
-            global_params=tx_global if prox else None)
-        n_k = np.zeros(W, np.float64)
-        n_k[:m] = self.ds.n_per_client
-        return trained, n_k
+        with obs.span("fl.train"):
+            cfg = self.cfg
+            W, m = cfg.clients_per_round, len(sel)
+            ks = jax.random.split(self.key, m + 1)
+            self.key = ks[0]
+            ks = obs.sync(ks)          # the client keys, read back once
+            obs.count("cohort_pad_slots", W - m)
+            keys = np.empty((W,) + ks.shape[1:], dtype=ks.dtype)
+            keys[:m] = ks[1:]
+            keys[m:] = keys[0]
+            idx = np.zeros(W, np.int64)
+            idx[:m] = sel
+            ep = np.ones(W, np.int32)
+            ep[:m] = epochs
+            tx_global = self._tx_global()
+            stacked = jax.tree.map(
+                lambda p: jnp.broadcast_to(p, (W,) + p.shape), tx_global)
+            gather = jnp.asarray(idx)
+            trained = local_sgd_clients(
+                cfg.model, stacked, self.ds.x[gather], self.ds.y[gather],
+                jnp.asarray(keys), ep, cfg.batch_size, cfg.lr,
+                mu=cfg.prox_mu if prox else 0.0,
+                global_params=tx_global if prox else None)
+            n_k = np.zeros(W, np.float64)
+            n_k[:m] = self.ds.n_per_client
+            return trained, n_k
 
     # -- fault resolution ------------------------------------------------
     def _next_available_contact(self, k: int, t: float):
@@ -915,10 +926,12 @@ class SpaceifiedFL:
 
     # -- evaluation ------------------------------------------------------
     def evaluate(self) -> float:
-        return accuracy(self.apply_fn, self.global_params,
-                        self.ds.x_test, self.ds.y_test)
+        with obs.span("fl.evaluate"):
+            return accuracy(self.apply_fn, self.global_params,
+                            self.ds.x_test, self.ds.y_test)
 
     # -- main loop (discrete-event core) ---------------------------------
+    @obs.traced_run
     def run(self, t0: float = 0.0, t_end: Optional[float] = None,
             max_rounds: Optional[int] = None):
         """Event-driven main loop. ROUND_BARRIER decision events on a
@@ -932,29 +945,33 @@ class SpaceifiedFL:
         resolve in one batched ``WorldTimeline.advance_through`` pass per
         round instead of per-event Python stepping; battery-floor
         crossings are noted by diffing the gating mask at each barrier.
-        ``self.event_stats`` holds the per-kind counts afterwards."""
+        ``self.event_stats`` holds the per-kind counts afterwards, and
+        ``self.trace`` the run's spans and counters (``repro.obs``)."""
         t_end = t_end if t_end is not None else self.plan.horizon_s
         max_rounds = max_rounds or self.cfg.max_rounds
         queue = EventQueue()
         queue.push(t0, ROUND_BARRIER)
         timeline = WorldTimeline.for_fl(self.plan, self.energy, self.faults)
-        self.event_stats = st = timeline.stats
+        self.event_stats = self.trace.events = st = timeline.stats
         r = 0
         while queue and r < max_rounds:
             ev = queue.pop()
             if ev.t >= t_end:
                 break
             st.add(ROUND_BARRIER)
-            rec = self.run_round(r, ev.t)
-            if rec is None:
-                break
-            self.records.append(rec)
-            timeline.advance_through(rec.t_end)
-            st.add(TRAIN_DONE, len(rec.participants))
-            if self.energy is not None:
-                timeline.note_eligibility(self.energy.eligible(), rec.t_end)
-            queue.push(rec.t_end, ROUND_BARRIER)
+            with obs.span("fl.round"):
+                rec = self.run_round(r, ev.t)
+                if rec is None:
+                    break
+                self.records.append(rec)
+                timeline.advance_through(rec.t_end)
+                st.add(TRAIN_DONE, len(rec.participants))
+                if self.energy is not None:
+                    timeline.note_eligibility(self.energy.eligible(),
+                                              rec.t_end)
+                queue.push(rec.t_end, ROUND_BARRIER)
             r += 1
+            self.trace.round_done()
         return self.records
 
     def run_round(self, r: int, t: float) -> Optional[RoundRecord]:
@@ -968,8 +985,9 @@ class FedAvgSat(SpaceifiedFL):
 
     def run_round(self, r, t):
         cfg = self.cfg
-        proj = self._projected_returns(t, cfg.epochs)
-        sel = self._select_from_projections(proj, t)
+        with obs.span("fl.select"):
+            proj = self._projected_returns(t, cfg.epochs)
+            sel = self._select_from_projections(proj, t)
         pol_skips = self._policy_skips
         if not sel:
             return None
@@ -1014,10 +1032,11 @@ class FedAvgSat(SpaceifiedFL):
                                           float(ends[int(i)]), r,
                                           int(sel[int(i)]))
             n_k[:len(sel)] *= on_time.astype(np.float64)
-        if float(n_k.sum()) > 0.0:         # always true when faults are off
-            self.global_params, n_clip = self._aggregate(trained, n_k)
-        if self._carried:
-            self._fold_carried(t_round_end, r)
+        with obs.span("fl.aggregate"):
+            if float(n_k.sum()) > 0.0:     # always true when faults are off
+                self.global_params, n_clip = self._aggregate(trained, n_k)
+            if self._carried:
+                self._fold_carried(t_round_end, r)
         wh, skipped = self._round_energy(proj, ks, trains, comms, t_round_end)
         acc = self.evaluate() if r % cfg.eval_every == 0 else \
             (self.records[-1].accuracy if self.records else 0.0)
@@ -1051,25 +1070,26 @@ class FedProxSat(SpaceifiedFL):
 
     def run_round(self, r, t):
         cfg = self.cfg
-        proj = self._projected_returns(t, cfg.epochs)
-        sel = self._select_from_projections(proj, t)
-        pol_skips = self._policy_skips
-        if not sel:
-            return None
-        floor_ep = max(cfg.min_epochs, 1)
-        # ONE contact-plan pass per round: the floor projection reuses
-        # the selection projection's first-contact query + energy/fault
-        # masks (identical at the same t — bitwise), re-running only the
-        # epoch-dependent return leg; when the floor equals the selection
-        # epoch count the projections coincide entirely.
-        projf = proj if floor_ep == cfg.epochs else \
-            self._projected_returns(t, floor_ep, base=proj)
-        # refilter under the floor projection through the policy's
-        # eligibility (for the built-ins this IS projf["valid"] — the
-        # exact pre-policy refilter)
-        floor_ok = self.policy.decide(
-            self._policy_inputs(projf, t, floor_ep)).eligible
-        sel = [k for k in sel if floor_ok[k]]
+        with obs.span("fl.select"):
+            proj = self._projected_returns(t, cfg.epochs)
+            sel = self._select_from_projections(proj, t)
+            pol_skips = self._policy_skips
+            if not sel:
+                return None
+            floor_ep = max(cfg.min_epochs, 1)
+            # ONE contact-plan pass per round: the floor projection reuses
+            # the selection projection's first-contact query + energy/
+            # fault masks (identical at the same t — bitwise), re-running
+            # only the epoch-dependent return leg; when the floor equals
+            # the selection epoch count the projections coincide entirely.
+            projf = proj if floor_ep == cfg.epochs else \
+                self._projected_returns(t, floor_ep, base=proj)
+            # refilter under the floor projection through the policy's
+            # eligibility (for the built-ins this IS projf["valid"] — the
+            # exact pre-policy refilter)
+            floor_ok = self.policy.decide(
+                self._policy_inputs(projf, t, floor_ep)).eligible
+            sel = [k for k in sel if floor_ok[k]]
         if not sel:
             return None
         ks = np.asarray(sel)
@@ -1112,10 +1132,11 @@ class FedProxSat(SpaceifiedFL):
                                           float(ends[int(i)]), r,
                                           int(sel[int(i)]))
             n_k[:len(sel)] *= on_time.astype(np.float64)
-        if float(n_k.sum()) > 0.0:
-            self.global_params, n_clip = self._aggregate(trained, n_k)
-        if self._carried:
-            self._fold_carried(t_round_end, r)
+        with obs.span("fl.aggregate"):
+            if float(n_k.sum()) > 0.0:
+                self.global_params, n_clip = self._aggregate(trained, n_k)
+            if self._carried:
+                self._fold_carried(t_round_end, r)
         wh, skipped = self._round_energy(projf, ks, trains, comms,
                                          t_round_end)
         acc = self.evaluate() if r % cfg.eval_every == 0 else \
@@ -1184,6 +1205,7 @@ class FedBuffSat(SpaceifiedFL):
                 self.global_params, stacked_new, stacked_base, wgts)
         self._last_flush_clipped = n_clip
 
+    @obs.traced_run
     def run(self, t0: float = 0.0, t_end: Optional[float] = None,
             max_rounds: Optional[int] = None):
         cfg, plan = self.cfg, self.plan
@@ -1197,7 +1219,7 @@ class FedBuffSat(SpaceifiedFL):
         # the timeline between pops
         queue = EventQueue()
         timeline = WorldTimeline.for_fl(self.plan, self.energy, self.faults)
-        self.event_stats = st = timeline.stats
+        self.event_stats = self.trace.events = st = timeline.stats
         # client states: params version picked up, pickup round, pickup time
         client_params: Dict[int, object] = {}
         pickup_round: Dict[int, int] = {}
@@ -1219,83 +1241,84 @@ class FedBuffSat(SpaceifiedFL):
         # satellites query from their (batched) battery-recovery time
         # instead of t0 — satellites that never recover get an inf query,
         # which next_contacts reports as invalid.
-        tq = np.full(K, t0)
-        rex_seed = 0        # retry-budget exhaustions during seeding
-        def_seed = 0        # policy eclipse-deferrals during seeding
-        if self.energy is not None:
-            self.energy.advance_to(t0)
-            if self.policy.defers_in_eclipse:
-                # the policy's sunlit-arc deferral replaces the binary
-                # floor at seeding: a satellite in eclipse below the
-                # defer threshold schedules its first pickup from its
-                # sunrise (solar income) instead of the floor-recovery
-                # walk; one held dark forever sits the run out
-                soc = self.energy.soc_frac()
-                defer = ~self.energy.sunlit_at(t0) \
-                    & (soc < self.policy.defer_soc)
-                if defer.any():
-                    sr = self.energy.sunrise_after(t0)
-                    tq[defer] = np.where(np.isfinite(sr[defer]),
-                                         np.maximum(sr[defer], t0), np.inf)
-                    def_seed = int(defer.sum())
+        with obs.span("fl.select"):
+            tq = np.full(K, t0)
+            rex_seed = 0        # retry-budget exhaustions during seeding
+            def_seed = 0        # policy eclipse-deferrals during seeding
+            if self.energy is not None:
+                self.energy.advance_to(t0)
+                if self.policy.defers_in_eclipse:
+                    # the policy's sunlit-arc deferral replaces the binary
+                    # floor at seeding: a satellite in eclipse below the
+                    # defer threshold schedules its first pickup from its
+                    # sunrise (solar income) instead of the floor-recovery
+                    # walk; one held dark forever sits the run out
+                    soc = self.energy.soc_frac()
+                    defer = ~self.energy.sunlit_at(t0) \
+                        & (soc < self.policy.defer_soc)
+                    if defer.any():
+                        sr = self.energy.sunrise_after(t0)
+                        tq[defer] = np.where(np.isfinite(sr[defer]),
+                                             np.maximum(sr[defer], t0), np.inf)
+                        def_seed = int(defer.sum())
+                else:
+                    drained = np.nonzero(~self.energy.eligible())[0]
+                    if len(drained):
+                        rts = self.energy.recover_times(drained)
+                        tq[drained] = np.where(np.isfinite(rts),
+                                               np.maximum(rts, t0), np.inf)
+            if self.faults is None:
+                avail, _, _, valid = plan.next_contacts(tq)
+                recv_end_k = avail + self._t_up_k
+                ret_avail, _, _, ret_valid = plan.next_contacts(
+                    np.where(valid, recv_end_k + ep_s, np.inf))
+                for k in range(K):
+                    if not (valid[k] and ret_valid[k]):
+                        continue
+                    recv_end, ret0 = float(recv_end_k[k]), float(ret_avail[k])
+                    ep = int(np.clip((ret0 - recv_end) // ep_s[k], 1,
+                                     cfg.max_local_epochs))
+                    queue.push(ret0 + float(self._t_down_k[k]),
+                               CLIENT_RETURN, key=k)
+                    client_params[k] = self._tx_global()
+                    pickup_round[k] = 0
+                    epochs_of[k] = ep
+                    idle_of[k] = max(ret0 - (recv_end + ep * float(ep_s[k])),
+                                     0.0)
+                    if self.energy is not None:     # the seed pickup's uplink
+                        deferred_up[k] = float(self._t_up_k[k])
             else:
-                drained = np.nonzero(~self.energy.eligible())[0]
-                if len(drained):
-                    rts = self.energy.recover_times(drained)
-                    tq[drained] = np.where(np.isfinite(rts),
-                                           np.maximum(rts, t0), np.inf)
-        if self.faults is None:
-            avail, _, _, valid = plan.next_contacts(tq)
-            recv_end_k = avail + self._t_up_k
-            ret_avail, _, _, ret_valid = plan.next_contacts(
-                np.where(valid, recv_end_k + ep_s, np.inf))
-            for k in range(K):
-                if not (valid[k] and ret_valid[k]):
-                    continue
-                recv_end, ret0 = float(recv_end_k[k]), float(ret_avail[k])
-                ep = int(np.clip((ret0 - recv_end) // ep_s[k], 1,
-                                 cfg.max_local_epochs))
-                queue.push(ret0 + float(self._t_down_k[k]),
-                           CLIENT_RETURN, key=k)
-                client_params[k] = self._tx_global()
-                pickup_round[k] = 0
-                epochs_of[k] = ep
-                idle_of[k] = max(ret0 - (recv_end + ep * float(ep_s[k])),
-                                 0.0)
-                if self.energy is not None:     # the seed pickup's uplink
-                    deferred_up[k] = float(self._t_up_k[k])
-        else:
-            # fault-aware seed: outage-delayed pickups, outage-skipping
-            # return windows, and the drop walk resolved at scheduling
-            # time (the trained content never depends on the return time,
-            # so resolving drops early is equivalent; staleness accrues
-            # naturally from the later event time).
-            tq = self.faults.next_up(np.arange(K), tq)
-            for k in range(K):
-                w = self._next_available_contact(k, float(tq[k]))
-                if w is None:
-                    continue
-                recv_end = float(w[0]) + float(self._t_up_k[k])
-                nxt = self._next_available_contact(
-                    k, recv_end + float(ep_s[k]))
-                if nxt is None:
-                    continue
-                ep = int(np.clip((nxt[0] - recv_end) // ep_s[k], 1,
-                                 cfg.max_local_epochs))
-                t_done, d, rb, lost = self._walk_drops(k, nxt)
-                if lost:            # every return window drops: sits out
-                    rex_seed += int(lost == _LOST_RETRIES)
-                    continue
-                queue.push(t_done, CLIENT_RETURN, key=k)
-                client_params[k] = self._tx_global()
-                pickup_round[k] = 0
-                epochs_of[k] = ep
-                idle_of[k] = max(nxt[0] - (recv_end + ep * float(ep_s[k])),
-                                 0.0)
-                pickup_t[k] = float(w[0])
-                meta_of[k] = (d, rb)
-                if self.energy is not None:
-                    deferred_up[k] = float(self._t_up_k[k])
+                # fault-aware seed: outage-delayed pickups, outage-skipping
+                # return windows, and the drop walk resolved at scheduling
+                # time (the trained content never depends on the return time,
+                # so resolving drops early is equivalent; staleness accrues
+                # naturally from the later event time).
+                tq = self.faults.next_up(np.arange(K), tq)
+                for k in range(K):
+                    w = self._next_available_contact(k, float(tq[k]))
+                    if w is None:
+                        continue
+                    recv_end = float(w[0]) + float(self._t_up_k[k])
+                    nxt = self._next_available_contact(
+                        k, recv_end + float(ep_s[k]))
+                    if nxt is None:
+                        continue
+                    ep = int(np.clip((nxt[0] - recv_end) // ep_s[k], 1,
+                                     cfg.max_local_epochs))
+                    t_done, d, rb, lost = self._walk_drops(k, nxt)
+                    if lost:            # every return window drops: sits out
+                        rex_seed += int(lost == _LOST_RETRIES)
+                        continue
+                    queue.push(t_done, CLIENT_RETURN, key=k)
+                    client_params[k] = self._tx_global()
+                    pickup_round[k] = 0
+                    epochs_of[k] = ep
+                    idle_of[k] = max(nxt[0] - (recv_end + ep * float(ep_s[k])),
+                                     0.0)
+                    pickup_t[k] = float(w[0])
+                    meta_of[k] = (d, rb)
+                    if self.energy is not None:
+                        deferred_up[k] = float(self._t_up_k[k])
 
         buf, r = [], 0
         t_round_start = t0
@@ -1309,184 +1332,200 @@ class FedBuffSat(SpaceifiedFL):
             t_ret, k = ev.t, ev.key
             if t_ret > t_end:
                 break
-            timeline.advance_through(t_ret)
-            st.add(CLIENT_RETURN)
-            t_up, t_down = float(self._t_up_k[k]), float(self._t_down_k[k])
-            train_s = epochs_of[k] * float(ep_s[k])
-            # a radiation reset since pickup wiped the client's local
-            # state: the episode's update (and any in-flight downlink) is
-            # lost. Nothing is billed — the reset, not the radio, lost it
-            # — and the client re-syncs by picking up the current global
-            # at this same contact.
-            wiped = (self.faults is not None and self.faults.cfg.has_resets
-                     and self.faults.reset_in(k, pickup_t.get(k, t0), t_ret))
-            n_drops = 0
-            if not wiped:
-                self.key, sub = jax.random.split(self.key)
-                trained = local_sgd(cfg.model, client_params[k],
-                                    self.ds.x[k], self.ds.y[k], sub,
-                                    epochs_of[k], cfg.batch_size, cfg.lr,
-                                    cfg.prox_mu, True, client_params[k])
-                if cfg.quant_bits:  # the returned model crosses the radio
-                    trained = quantize_roundtrip(trained, cfg.quant_bits)
-                if self.faults is not None \
-                        and self.faults.cfg.has_payload_faults:
-                    # the payload may be corrupted/poisoned in flight:
-                    # the delivery still bills its bytes, the buffered
-                    # weights are what went bad. Reference = the pickup
-                    # version the client trained from.
-                    trained, bad = self._payload_fault_model(
-                        k, trained, t_ret, client_params[k])
-                    corr_acc += int(bad)
-                stale = r - pickup_round[k]
-                wgt = (1.0 + stale) ** (-cfg.staleness_exponent)
-                buf.append((trained, client_params[k], wgt))
-                comm_acc += t_up + t_down
-                comm_by[k] = comm_by.get(k, 0.0) + t_up + t_down
-                train_acc += train_s
-                idle_acc += idle_of.get(k, 0.0)
-                n_ev += 1
-                st.add(TRAIN_DONE)
-                if self.faults is not None:
-                    # the drop walk resolved at scheduling time: retry
-                    # airtime joins the episode's comm accounting
-                    n_drops, rb = meta_of.get(k, (0, 0.0))
-                    drop_acc += n_drops
-                    rebill_acc += rb
-                    comm_acc += n_drops * t_down
-                    comm_by[k] = comm_by.get(k, 0.0) + n_drops * t_down
-            else:
-                fault_acc += 1
-                deferred_up.pop(k, None)
-            # client immediately picks up the current global and continues
-            recv_end = t_ret + t_up
-            requeue, stood_down = True, False
-            if self.energy is not None:
-                self.energy.advance_to(t_ret)
-                # the completed episode is billed at its return contact:
-                # training, the downlink(s) that just happened — retries
-                # included — and any pickup uplink deferred past a
-                # stand-down (whose contact the clock has now passed)
+            with obs.span("fl.return"):
+                timeline.advance_through(t_ret)
+                st.add(CLIENT_RETURN)
+                t_up = float(self._t_up_k[k])
+                t_down = float(self._t_down_k[k])
+                train_s = epochs_of[k] * float(ep_s[k])
+                # a radiation reset since pickup wiped the client's local
+                # state: the episode's update (and any in-flight downlink)
+                # is lost. Nothing is billed — the reset, not the radio, lost
+                # it — and the client re-syncs by picking up the current
+                # global at this same contact.
+                wiped = (self.faults is not None
+                         and self.faults.cfg.has_resets
+                         and self.faults.reset_in(k, pickup_t.get(k, t0),
+                                                  t_ret))
+                n_drops = 0
                 if not wiped:
-                    energy_acc += self.energy.bill_activity(
-                        np.array([k]), np.array([train_s]),
-                        np.array([t_down * (1 + n_drops)
-                                  + deferred_up.pop(k, 0.0)]))
-                elig = self.energy.eligible()
-                timeline.note_eligibility(elig, t_ret)
-                if self.policy.defers_in_eclipse:
-                    # the policy's sunlit-arc deferral replaces the
-                    # binary floor stand-down: in eclipse below the
-                    # defer threshold, the next pickup waits for this
-                    # satellite's sunrise (when solar income resumes)
-                    # instead of walking to the SoC-floor recovery
-                    if float(self.energy.soc_frac()[k]) \
-                            < self.policy.defer_soc \
-                            and not bool(self.energy.sunlit_at(t_ret)[k]):
-                        def_acc += 1
-                        stood_down = True
-                        sr = float(self.energy.sunrise_after(t_ret)[k])
-                        w2 = self._next_available_contact(
-                            k, max(sr, recv_end)) if np.isfinite(sr) \
-                            else None
-                        if w2 is None:
-                            requeue = False  # dark forever: drops out
-                        else:
-                            recv_end = w2[0] + t_up
-                elif not elig[k]:
-                    # drained below the floor: stand down until idle+solar
-                    # recovers, then rejoin at the next contact after that.
-                    # The deferred pickup's uplink is billed where it
-                    # actually happens (post-recovery), not here — at this
-                    # point the battery could not pay it and the charge
-                    # would vanish into the SoC floor clamp.
-                    skip_acc += 1
-                    stood_down = True
-                    w2 = self._post_recovery_contact(k, recv_end)
-                    if w2 is None:
-                        requeue = False     # never recovers: drops out
-                    else:
-                        recv_end = w2[0] + t_up
-            nxt = self._next_available_contact(k, recv_end + float(ep_s[k])) \
-                if requeue else None
-            ev_t, d2, rb2 = None, 0, 0.0
-            if nxt is not None:
-                ev_t = float(nxt[0]) + t_down
-                if self.faults is not None:
-                    t_done2, d2, rb2, lost = self._walk_drops(k, nxt)
-                    if lost:        # every remaining return window drops
-                        rex_acc += int(lost == _LOST_RETRIES)
-                        nxt = None
-                    else:
-                        ev_t = t_done2
-            if nxt is not None:
-                # the next pickup really starts an episode: bill its uplink
-                # — now, if it happens at this same contact; via
-                # deferred_up at the post-recovery contact otherwise. A
-                # client with no remaining return contact performs no
-                # pickup, so (symmetrically in both paths) none is billed.
-                if self.energy is not None:
-                    if stood_down:
-                        deferred_up[k] = t_up
-                    else:
-                        energy_acc += self.energy.bill_activity(
-                            np.array([k]), np.array([0.0]),
-                            np.array([t_up]))
-                ep = int(np.clip((nxt[0] - recv_end) // ep_s[k], 1,
-                                 cfg.max_local_epochs))
-                queue.push(ev_t, CLIENT_RETURN, key=k)
-                client_params[k] = self._tx_global()
-                pickup_round[k] = r
-                epochs_of[k] = ep
-                idle_of[k] = max(nxt[0] - (recv_end + ep * float(ep_s[k])),
-                                 0.0)
-                if self.faults is not None:
-                    pickup_t[k] = recv_end - t_up
-                    meta_of[k] = (d2, rb2)
-            elif self.energy is not None or self.faults is not None:
-                # the client drops out of the pending set for good (no
-                # recovery contact, or no usable window left): purge its
-                # per-client state so nothing dangles — in particular
-                # epochs_of, whose stale entry would skew every later
-                # round's epoch average. No bytes are billed for a pickup
-                # that never happens. (Gated so the fault-free/energy-free
-                # path stays byte-identical to round_engine_ref.)
-                for dct in (client_params, pickup_round, epochs_of,
-                            idle_of, deferred_up, pickup_t, meta_of):
-                    dct.pop(k, None)
+                    with obs.span("fl.train"):
+                        self.key, sub = jax.random.split(self.key)
+                        trained = local_sgd(
+                            cfg.model, client_params[k], self.ds.x[k],
+                            self.ds.y[k], sub, epochs_of[k], cfg.batch_size,
+                            cfg.lr, cfg.prox_mu, True, client_params[k])
+                        if cfg.quant_bits:  # the returned model crosses
+                            trained = quantize_roundtrip(  # the radio
+                                trained, cfg.quant_bits)
+                    if self.faults is not None \
+                            and self.faults.cfg.has_payload_faults:
+                        # the payload may be corrupted/poisoned in flight:
+                        # the delivery still bills its bytes, the buffered
+                        # weights are what went bad. Reference = the pickup
+                        # version the client trained from.
+                        trained, bad = self._payload_fault_model(
+                            k, trained, t_ret, client_params[k])
+                        corr_acc += int(bad)
+                    stale = r - pickup_round[k]
+                    wgt = (1.0 + stale) ** (-cfg.staleness_exponent)
+                    buf.append((trained, client_params[k], wgt))
+                    comm_acc += t_up + t_down
+                    comm_by[k] = comm_by.get(k, 0.0) + t_up + t_down
+                    train_acc += train_s
+                    idle_acc += idle_of.get(k, 0.0)
+                    n_ev += 1
+                    st.add(TRAIN_DONE)
+                    if self.faults is not None:
+                        # the drop walk resolved at scheduling time: retry
+                        # airtime joins the episode's comm accounting
+                        n_drops, rb = meta_of.get(k, (0, 0.0))
+                        drop_acc += n_drops
+                        rebill_acc += rb
+                        comm_acc += n_drops * t_down
+                        comm_by[k] = comm_by.get(k, 0.0) \
+                            + n_drops * t_down
+                else:
+                    fault_acc += 1
+                    deferred_up.pop(k, None)
+                # client immediately picks up the current global and continues
+                with obs.span("fl.select"):
+                    recv_end = t_ret + t_up
+                    requeue, stood_down = True, False
+                    if self.energy is not None:
+                        self.energy.advance_to(t_ret)
+                        # the completed episode is billed at its return
+                        # contact: training, the downlink(s) that just
+                        # happened — retries included — and any pickup
+                        # uplink deferred past a stand-down (whose contact
+                        # the clock has now passed)
+                        if not wiped:
+                            energy_acc += self.energy.bill_activity(
+                                np.array([k]), np.array([train_s]),
+                                np.array([t_down * (1 + n_drops)
+                                          + deferred_up.pop(k, 0.0)]))
+                        elig = self.energy.eligible()
+                        timeline.note_eligibility(elig, t_ret)
+                        if self.policy.defers_in_eclipse:
+                            # the policy's sunlit-arc deferral replaces
+                            # the binary floor stand-down: in eclipse below
+                            # the defer threshold, the next pickup waits for
+                            # this satellite's sunrise (when solar income
+                            # resumes) instead of walking to the SoC-floor
+                            # recovery
+                            if float(self.energy.soc_frac()[k]) \
+                                    < self.policy.defer_soc and not bool(
+                                        self.energy.sunlit_at(t_ret)[k]):
+                                def_acc += 1
+                                stood_down = True
+                                sr = float(
+                                    self.energy.sunrise_after(t_ret)[k])
+                                w2 = self._next_available_contact(
+                                    k, max(sr, recv_end)) \
+                                    if np.isfinite(sr) else None
+                                if w2 is None:
+                                    requeue = False  # dark forever: out
+                                else:
+                                    recv_end = w2[0] + t_up
+                        elif not elig[k]:
+                            # drained below the floor: stand down until
+                            # idle+solar recovers, then rejoin at the next
+                            # contact after that. The deferred pickup's
+                            # uplink is billed where it actually happens
+                            # (post-recovery), not here — at this point the
+                            # battery could not pay it and the charge would
+                            # vanish into the SoC floor clamp.
+                            skip_acc += 1
+                            stood_down = True
+                            w2 = self._post_recovery_contact(k, recv_end)
+                            if w2 is None:
+                                requeue = False  # never recovers: out
+                            else:
+                                recv_end = w2[0] + t_up
+                    nxt = self._next_available_contact(
+                        k, recv_end + float(ep_s[k])) if requeue else None
+                    ev_t, d2, rb2 = None, 0, 0.0
+                    if nxt is not None:
+                        ev_t = float(nxt[0]) + t_down
+                        if self.faults is not None:
+                            t_done2, d2, rb2, lost = self._walk_drops(k, nxt)
+                            if lost:    # every remaining return window drops
+                                rex_acc += int(lost == _LOST_RETRIES)
+                                nxt = None
+                            else:
+                                ev_t = t_done2
+                    if nxt is not None:
+                        # the next pickup really starts an episode: bill its
+                        # uplink — now, if it happens at this same contact;
+                        # via deferred_up at the post-recovery contact
+                        # otherwise. A client with no remaining return
+                        # contact performs no pickup, so (symmetrically in
+                        # both paths) none is billed.
+                        if self.energy is not None:
+                            if stood_down:
+                                deferred_up[k] = t_up
+                            else:
+                                energy_acc += self.energy.bill_activity(
+                                    np.array([k]), np.array([0.0]),
+                                    np.array([t_up]))
+                        ep = int(np.clip((nxt[0] - recv_end) // ep_s[k], 1,
+                                         cfg.max_local_epochs))
+                        queue.push(ev_t, CLIENT_RETURN, key=k)
+                        client_params[k] = self._tx_global()
+                        pickup_round[k] = r
+                        epochs_of[k] = ep
+                        idle_of[k] = max(
+                            nxt[0] - (recv_end + ep * float(ep_s[k])), 0.0)
+                        if self.faults is not None:
+                            pickup_t[k] = recv_end - t_up
+                            meta_of[k] = (d2, rb2)
+                    elif self.energy is not None or self.faults is not None:
+                        # the client drops out of the pending set for good
+                        # (no recovery contact, or no usable window left):
+                        # purge its per-client state so nothing dangles — in
+                        # particular epochs_of, whose stale entry would skew
+                        # every later round's epoch average. No bytes are
+                        # billed for a pickup that never happens. (Gated so
+                        # the fault-free/energy-free path stays
+                        # byte-identical to round_engine_ref.)
+                        for dct in (client_params, pickup_round, epochs_of,
+                                    idle_of, deferred_up, pickup_t, meta_of):
+                            dct.pop(k, None)
 
-            if len(buf) >= cfg.buffer_size:
-                st.add(ROUND_BARRIER)
-                self._flush_buffer(buf)
-                n_clip = self._last_flush_clipped
-                buf = []
-                acc = self.evaluate() if r % cfg.eval_every == 0 else \
-                    (self.records[-1].accuracy if self.records else 0.0)
-                dur = t_ret - t_round_start
-                self.records.append(RoundRecord(
-                    r, t_round_start, t_ret, dur,
-                    idle_acc / max(n_ev, 1),
-                    comm_acc / max(n_ev, 1), train_acc / max(n_ev, 1),
-                    acc, [],
-                    epochs=float(np.mean(list(epochs_of.values())))
-                    if epochs_of else 0.0,
-                    energy_wh=energy_acc, skipped_low_power=skip_acc,
-                    comm_s_by_sat=comm_by, skipped_faulted=fault_acc,
-                    dropped_contacts=drop_acc, retransmit_bytes=rebill_acc,
-                    corrupted_updates=corr_acc, clipped_updates=n_clip,
-                    retries_exhausted=rex_acc,
-                    storm_events=self._storms_in(t_round_start, t_ret),
-                    policy_deferred=def_acc,
-                    policy_skips={"eclipse_deferred": def_acc}
-                    if def_acc else {}))
-                t_round_start = t_ret
-                idle_acc = comm_acc = train_acc = 0.0
-                energy_acc, skip_acc = 0.0, 0
-                fault_acc, drop_acc, rebill_acc = 0, 0, 0.0
-                corr_acc, rex_acc, def_acc = 0, 0, 0
-                comm_by = {}
-                n_ev = 0
-                r += 1
+                if len(buf) >= cfg.buffer_size:
+                    st.add(ROUND_BARRIER)
+                    with obs.span("fl.aggregate"):
+                        self._flush_buffer(buf)
+                    n_clip = self._last_flush_clipped
+                    buf = []
+                    acc = self.evaluate() if r % cfg.eval_every == 0 else \
+                        (self.records[-1].accuracy if self.records else 0.0)
+                    dur = t_ret - t_round_start
+                    self.records.append(RoundRecord(
+                        r, t_round_start, t_ret, dur,
+                        idle_acc / max(n_ev, 1),
+                        comm_acc / max(n_ev, 1), train_acc / max(n_ev, 1),
+                        acc, [],
+                        epochs=float(np.mean(list(epochs_of.values())))
+                        if epochs_of else 0.0,
+                        energy_wh=energy_acc, skipped_low_power=skip_acc,
+                        comm_s_by_sat=comm_by, skipped_faulted=fault_acc,
+                        dropped_contacts=drop_acc, retransmit_bytes=rebill_acc,
+                        corrupted_updates=corr_acc, clipped_updates=n_clip,
+                        retries_exhausted=rex_acc,
+                        storm_events=self._storms_in(t_round_start, t_ret),
+                        policy_deferred=def_acc,
+                        policy_skips={"eclipse_deferred": def_acc}
+                        if def_acc else {}))
+                    t_round_start = t_ret
+                    idle_acc = comm_acc = train_acc = 0.0
+                    energy_acc, skip_acc = 0.0, 0
+                    fault_acc, drop_acc, rebill_acc = 0, 0, 0.0
+                    corr_acc, rex_acc, def_acc = 0, 0, 0
+                    comm_by = {}
+                    n_ev = 0
+                    r += 1
+                    self.trace.round_done()
         return self.records
 
 

@@ -7,6 +7,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
+
 
 def init_cnn(key, input_shape, n_classes, width=16):
     h, w, c = input_shape
@@ -106,4 +108,5 @@ def _accuracy_fn(apply_fn, batch):
 
 
 def accuracy(apply_fn, params, x, y, batch=256):
-    return int(_accuracy_fn(apply_fn, batch)(params, x, y)) / x.shape[0]
+    return int(obs.sync(_accuracy_fn(apply_fn, batch)(params, x, y))) \
+        / x.shape[0]

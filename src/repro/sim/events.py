@@ -48,6 +48,8 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 # -- event taxonomy ---------------------------------------------------------
 # World events (state transitions; resolve first at equal timestamps, in
 # this priority order) ...
@@ -278,13 +280,14 @@ class WorldTimeline:
         if t < self.t:
             return 0
         n_total = 0
-        for i, times in enumerate(self._times):
-            c = self._cursor[i]
-            j = int(np.searchsorted(times, t, side="right"))
-            if j > c:
-                self.stats.add(self._kinds[i], j - c)
-                self._cursor[i] = j
-                n_total += j - c
+        with obs.span("world.advance"):
+            for i, times in enumerate(self._times):
+                c = self._cursor[i]
+                j = int(np.searchsorted(times, t, side="right"))
+                if j > c:
+                    self.stats.add(self._kinds[i], j - c)
+                    self._cursor[i] = j
+                    n_total += j - c
         self.t = t
         self.stats.batched_passes += 1
         return n_total

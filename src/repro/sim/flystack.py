@@ -16,6 +16,7 @@ from repro.core.autoflsat import AutoFLSat
 from repro.core.contact_plan import ContactPlan, build_contact_plan
 from repro.core.spaceify import ALGORITHMS, FLConfig, RoundRecord
 from repro.data.synthetic import FedDataset, make_federated_dataset
+from repro.obs import RunTrace
 from repro.sim.hardware import FLYCUBE, FleetProfile, HardwareProfile
 
 
@@ -77,8 +78,13 @@ class SimConfig:
 
 @dataclasses.dataclass
 class SimResult:
+    """A run's ``records`` (the simulated rounds) and its ``trace``: the
+    engine's spans and counters on the host (``repro.obs.RunTrace``),
+    with the discrete-event counts as ``trace.events``.
+    ``trace.summary()`` says where the wall time went."""
     config: SimConfig
     records: List[RoundRecord]
+    trace: Optional[RunTrace] = None
 
     # -- paper metrics ---------------------------------------------------
     def final_accuracy(self) -> float:
@@ -245,4 +251,4 @@ class FLySTacK:
             fl = dataclasses.replace(fl, **overrides)
             algo = cls(self.plan, self.hw, self.dataset, fl)
         records = algo.run()
-        return SimResult(config=cfg, records=records)
+        return SimResult(config=cfg, records=records, trace=algo.trace)

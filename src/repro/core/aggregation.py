@@ -101,13 +101,16 @@ def inplace_aggregate(updates: Iterable[Tuple], template=None):
     return jax.tree.map(lambda a: a / total, acc)
 
 
+@partial(jax.jit, static_argnames=("bits", "mode"))
 def quantized_weighted_average(stacked_params, weights, bits: int,
                                mode: str = "auto"):
     """Weighted average over the QuAFL wire format: each client row of the
     stacked pytree is quantized to ``bits`` with its own per-tensor scale,
     then the server dequantizes + accumulates the whole cohort through the
     fused ``quant_agg`` kernel (``mode``: "auto" | "pallas" |
-    "pallas_interpret" | "jnp" — see repro.kernels.ops).
+    "pallas_interpret" | "jnp" — see repro.kernels.ops). One program per
+    call: the weights' normalisation, every leaf's quantization and the
+    kernel's tiling are traced in with the kernel.
 
     Zero-weight rows (padded cohort slots) contribute nothing: their
     weight*scale product is 0."""
@@ -130,10 +133,20 @@ def quantized_weighted_average(stacked_params, weights, bits: int,
 
 
 @jax.jit
-def apply_buffered_deltas(global_params, stacked_new, stacked_base, weights):
-    """FedBuff flush as one stacked reduction: global += mean_k of
-    weights[k] * (new_k - base_k). ``stacked_new``/``stacked_base`` carry a
-    leading buffer axis (D, ...); one trace per buffer size."""
+def stack_rows(rows):
+    """A sequence of pytrees of one structure -> one pytree whose leaves
+    carry a leading row axis (D, ...), in one program."""
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
+
+
+@jax.jit
+def apply_buffered_deltas(global_params, rows, weights):
+    """FedBuff flush as one program: global += mean_k of
+    weights[k] * (new_k - base_k). ``rows`` is the buffer's sequence of
+    ``(new_k, base_k)`` model pairs, stacked inside; one trace per buffer
+    length."""
+    stacked_new, stacked_base = stack_rows(rows)
+
     def upd(g, n, b):
         wb = weights.reshape((-1,) + (1,) * (n.ndim - 1))
         d = (wb * (n.astype(jnp.float32) - b.astype(jnp.float32))).mean(0)

@@ -91,7 +91,7 @@ from repro.core.aggregation import (apply_buffered_deltas,
                                     make_robust_aggregator,
                                     quantized_weighted_average,
                                     robust_apply_buffered_deltas,
-                                    weighted_average)
+                                    stack_rows, weighted_average)
 from repro.core.client import local_sgd, local_sgd_clients
 from repro.core.contact_plan import ContactPlan
 from repro.core.policy import PolicyInputs, resolve_policy, select_top
@@ -795,21 +795,31 @@ class SpaceifiedFL:
         if not due:
             return 0
         self._carried = [c for c in self._carried if c[2] > t_close]
-        stacked_new = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                   *[c[0] for c in due])
-        stacked_base = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                    *[c[1] for c in due])
-        wgts = jnp.asarray(
+        self._apply_deltas(
+            [c[:2] for c in due],
             [(1.0 + max(r - c[3], 0)) ** (-self.cfg.staleness_exponent)
-             for c in due], jnp.float32)
-        if self.aggregator is not None:
-            self.global_params, _ = robust_apply_buffered_deltas(
-                self.global_params, stacked_new, stacked_base, wgts,
-                self.aggregator, mode=self.cfg.quant_kernel)
-        else:
-            self.global_params = apply_buffered_deltas(
-                self.global_params, stacked_new, stacked_base, wgts)
+             for c in due])
         return len(due)
+
+    def _apply_deltas(self, rows, weights) -> int:
+        """``global += combine_k weights[k] * (new_k - base_k)`` over
+        ``rows`` of ``(new_k, base_k)`` models: the weighted mean in one
+        program (``apply_buffered_deltas``), or the robust estimator when
+        one is configured. Returns the estimator's attenuated row count
+        (0 for the mean). The FedBuff flush and the straggler fold."""
+        wgts = np.asarray(weights, np.float32)
+        if self.aggregator is None:
+            self.global_params = apply_buffered_deltas(
+                self.global_params, rows, wgts)
+            return 0
+        # robust: the estimator sees the staleness-weighted deltas (zero
+        # reference), so a poisoned or corrupted row is attenuated
+        # before it touches the global
+        stacked_new, stacked_base = stack_rows(rows)
+        self.global_params, n_att = robust_apply_buffered_deltas(
+            self.global_params, stacked_new, stacked_base, wgts,
+            self.aggregator, mode=self.cfg.quant_kernel)
+        return n_att
 
     def _storms_in(self, t_from: float, t_to: float) -> int:
         """Correlated storms breaking in ``(t_from, t_to]`` (0 when
@@ -1187,23 +1197,8 @@ class FedBuffSat(SpaceifiedFL):
         ``round_engine_ref`` shares ``weighted_average``, sharing the
         flush keeps the bitwise-parity gate about the *clock*, not the
         reduction tree. Sets ``self._last_flush_clipped``."""
-        stacked_new = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                   *[b[0] for b in buf])
-        stacked_base = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                    *[b[1] for b in buf])
-        wgts = jnp.asarray([b[2] for b in buf], jnp.float32)
-        n_clip = 0
-        if self.aggregator is not None:
-            # robust flush: the estimator sees the staleness-weighted
-            # deltas (zero reference), so a poisoned or corrupted
-            # buffered row is attenuated before it touches the global
-            self.global_params, n_clip = robust_apply_buffered_deltas(
-                self.global_params, stacked_new, stacked_base, wgts,
-                self.aggregator, mode=self.cfg.quant_kernel)
-        else:
-            self.global_params = apply_buffered_deltas(
-                self.global_params, stacked_new, stacked_base, wgts)
-        self._last_flush_clipped = n_clip
+        self._last_flush_clipped = self._apply_deltas(
+            [b[:2] for b in buf], [b[2] for b in buf])
 
     @obs.traced_run
     def run(self, t0: float = 0.0, t_end: Optional[float] = None,

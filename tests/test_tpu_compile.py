@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.aggregation import quantized_weighted_average
 from repro.core.client import _local_sgd_batch
 from repro.data.synthetic import DATASETS
 from repro.kernels.quant_agg import TILE_LANES, TILE_SUB, quant_agg_stacked_tiles
@@ -116,3 +117,22 @@ def test_cohort_trainer_compiles(one_chip):
         False, None).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_quantized_mean_is_one_program_holding_the_kernel(one_chip):
+    """The QuAFL server mean at the EuroSAT CNN's cohort shape compiles to
+    one module holding one ``quant_agg`` kernel call per leaf, under the
+    kernel's own name (the name the benchmark's roofline reader matches)."""
+    h, w, c, n_classes = DATASETS["eurosat"]
+    params = jax.eval_shape(lambda k: init_cnn(k, (h, w, c), n_classes),
+                            jax.random.PRNGKey(0))
+    stacked = jax.tree.map(
+        lambda p: _sds(one_chip, (COHORT,) + p.shape, p.dtype), params)
+    txt = quantized_weighted_average.lower(
+        stacked, _sds(one_chip, (COHORT,), jnp.float32), 8,
+        mode="pallas").compile().as_text()
+    calls = [ln for ln in txt.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == len(jax.tree_util.tree_leaves(params))
+    assert all(ln.split("=")[0].strip().lstrip("%").startswith(
+        "quant_agg_stacked_tiles") for ln in calls)

@@ -13,9 +13,10 @@ compiled kernel, that each kernel matches its jnp oracle on one trained
 cohort, and that both runs end above chance accuracy.
 
 Four chips: ``repro.launch.train``'s hierarchical trainer for mamba2-1.3b
-at full width, 2 clusters on a (pod=2, data=1, model=2) mesh: a few local
-steps, one tier-2 sync, and a check that both cluster replicas then equal
-the host-side mean of their pre-sync values.
+at full width, 2 clusters on a (pod=2, data=1, model=2) mesh: one round
+of the trainer's loop (a few local steps and one tier-2 sync), and a
+check that both cluster replicas then equal the host-side mean of their
+pre-sync values.
 
 Everything runs in this one process. Without a TPU it exits nonzero and
 prints no result. The last line of standard output is the JSON result,
@@ -242,8 +243,9 @@ def named_leaves(params, names) -> dict:
 
 
 def hfl_sync_check(argv) -> None:
-    """Train a few tier-1 steps, sync once, and compare each replica of
-    ``SYNC_LEAVES`` with the host mean of the pre-sync replicas."""
+    """Run the trainer's own loop (``train.run_hfl_rounds``) for one
+    round, and compare each replica of ``SYNC_LEAVES`` after its sync with
+    the host mean of the pre-sync replicas."""
     args = train.parse_args(argv)
     cfg = train.build_cfg(args)
     mesh = train.hfl_mesh(args.clusters)
@@ -258,15 +260,21 @@ def hfl_sync_check(argv) -> None:
     embed = state.params["tok_embed"]
     print(f"state placed: tok_embed {embed.shape} over "
           f"{len(embed.sharding.device_set)} devices", flush=True)
-    streams = train.cluster_streams(cfg, args)
-    for i in range(args.steps):
-        state, m = local(state, place_batch([next(s) for s in streams]))
-        loss = np.asarray(m["loss"])
-        print(f"step {i} loss/cluster {loss.tolist()} "
-              f"({time.perf_counter() - t0:.1f} s wall)", flush=True)
-        check(bool(np.all(np.isfinite(loss))), f"loss is not finite: {loss}")
-    before = named_leaves(state.params, SYNC_LEAVES)
-    state = sync(state)
+    before = {}
+
+    def checked_sync(state):
+        before.update(named_leaves(state.params, SYNC_LEAVES))
+        return sync(state)
+
+    state, losses = train.run_hfl_rounds(
+        state, local, checked_sync, place_batch,
+        zip(*train.cluster_streams(cfg, args)), int(args.sync_every),
+        args.steps, seed=args.seed)
+    for i, loss in enumerate(losses):
+        print(f"step {i} loss/cluster {loss.tolist()}", flush=True)
+    print(f"{args.steps} steps, sync every {args.sync_every}: "
+          f"{time.perf_counter() - t0:.1f} s wall", flush=True)
+    check(bool(np.all(np.isfinite(losses))), f"loss is not finite: {losses}")
     after = named_leaves(state.params, SYNC_LEAVES)
     for name in SYNC_LEAVES:
         spread = float(np.max(np.abs(before[name][0] - before[name][1])))

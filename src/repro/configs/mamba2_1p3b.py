@@ -11,7 +11,7 @@ CONFIG = ModelConfig(
     n_heads=0,
     n_kv_heads=0,
     d_ff=0,                       # mamba block doubles as mixer+mlp
-    vocab=50280,
+    vocab=50288,                  # 50,277 ids padded to a multiple of 16
     use_rope=False,
     norm_type="rmsnorm",
     ssm=SSMConfig(d_state=128, head_dim=64, expand=2, n_groups=1, conv_width=4),

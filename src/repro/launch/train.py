@@ -24,6 +24,7 @@ import time
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import save_pytree
 from repro.configs import get_config, get_smoke_config
 from repro.core import hierarchy as H
@@ -82,6 +83,42 @@ def hfl_sync(cfg, mesh, quant_bits: int = 0, cluster_weights=None):
                                        cluster_weights=cluster_weights),
                    in_shardings=(state_sh,), out_shardings=state_sh,
                    donate_argnums=0)
+
+
+def run_hfl_rounds(state, local, sync, place_batch, batches, h_sync: int,
+                   steps: int, seed=None):
+    """The hierarchical trainer's loop: ``steps`` tier-1 steps in tier-2
+    rounds of ``h_sync`` steps, each round closed by one ``sync``. A step
+    is ``local(state, place_batch(next(batches)))``, where ``batches``
+    yields one host batch per cluster; a last round shorter than
+    ``h_sync`` ends without a sync. Returns the new state and the
+    per-cluster loss of every step, (steps, C) on the host, read once a
+    round through ``obs.sync``. The call is recorded as one
+    ``obs.RunTrace`` of algorithm ``"hfl"`` under ``seed``."""
+    losses = []
+    with obs.logged_run(obs.RunTrace(seed, "hfl"), "hfl.run") as trace:
+        for start in range(0, steps, h_sync):
+            n = min(h_sync, steps - start)
+            with obs.span("hfl.round"):
+                metrics = []
+                for _ in range(n):
+                    cluster_batches = next(batches)
+                    with obs.span("hfl.place_batch"):
+                        batch = place_batch(cluster_batches)
+                    with obs.span("hfl.local"):
+                        state, m = local(state, batch)
+                    metrics.append(m["loss"])
+                    obs.count("hfl_steps")
+                    obs.count("tokens", sum(int(np.size(b["tokens"]))
+                                            for b in cluster_batches))
+                if n == h_sync:
+                    with obs.span("hfl.sync"):
+                        state = sync(state)
+                    obs.count("hfl_syncs")
+                losses += obs.sync(metrics)
+            if n == h_sync:
+                trace.round_done()
+    return state, np.asarray(losses, np.float32)
 
 
 def cluster_streams(cfg, args):
@@ -217,16 +254,16 @@ def main():
         else:
             h_sync = int(args.sync_every)
         sync = hfl_sync(cfg, mesh, args.quant_bits, cluster_w)
-        streams = cluster_streams(cfg, args)
-        for i in range(args.steps):
-            state, m = local(state, place_batch([next(s) for s in streams]))
-            if (i + 1) % h_sync == 0:
-                state = sync(state)
+        state, losses = run_hfl_rounds(
+            state, local, sync, place_batch,
+            zip(*cluster_streams(cfg, args)), h_sync, args.steps,
+            seed=args.seed)
+        for i, loss in enumerate(losses):
             if i % args.log_every == 0 or i == args.steps - 1:
                 print(f"step {i:4d} loss/cluster="
-                      f"{[round(float(x), 4) for x in m['loss']]} "
+                      f"{[round(float(x), 4) for x in loss]} "
                       f"({time.time() - t0:.1f}s)", flush=True)
-        final_loss = float(m["loss"].mean())
+        final_loss = float(losses[-1].mean())
     else:
         state = ST.init_train_state(key, cfg)
         step = jax.jit(ST.make_train_step(cfg, opt_cfg), donate_argnums=0)

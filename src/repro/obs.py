@@ -14,9 +14,11 @@ the read. Every other span's self time is the host's too, including any
 time a dispatch inside it blocks.
 
 A ``RunTrace`` covers one FL engine, its construction and its ``run()``;
-the engine activates it with ``recording`` and ``traced_run``. Finished
-runs go to a bounded in-process log, and ``runs(seeds)`` merges the runs
-of some FL seeds (the points of a sweep). Compilations are counted
+the engine activates it with ``recording`` and ``traced_run``. The LM
+trainer's ``run_hfl_rounds`` records one per call through
+``logged_run``. Finished runs go to a bounded in-process log, and
+``runs(seeds)`` merges the runs of some seeds (the points of a sweep, or
+the calls of a benchmark window). Compilations are counted
 through ``jax.monitoring`` and keyed by the innermost open span. The
 recorder keeps module state and assumes one thread drives the engines.
 """
@@ -187,19 +189,26 @@ def recording(trace: RunTrace):
         _active.pop()
 
 
+@contextlib.contextmanager
+def logged_run(trace: RunTrace, name: str):
+    """Record the block into ``trace`` under the span ``name``, start its
+    round clock, and log the trace when the block ends (also when it
+    raises)."""
+    try:
+        with recording(trace), span(name):
+            trace._mark = _now()
+            yield trace
+    finally:
+        _log.append(trace)
+
+
 def traced_run(method):
-    """Decorator of an engine's ``run``: records the call into the
-    engine's ``trace`` under the span ``fl.run``, starts its round clock,
-    and logs the trace when the call ends (also when it raises)."""
+    """Decorator of an engine's ``run``: the call is ``logged_run`` of the
+    engine's ``trace`` under the span ``fl.run``."""
     @functools.wraps(method)
     def run(self, *args, **kwargs):
-        trace = self.trace
-        try:
-            with recording(trace), span("fl.run"):
-                trace._mark = _now()
-                return method(self, *args, **kwargs)
-        finally:
-            _log.append(trace)
+        with logged_run(self.trace, "fl.run"):
+            return method(self, *args, **kwargs)
     return run
 
 

@@ -88,3 +88,35 @@ def test_hfl_programs_sync_to_the_cluster_mean():
     np.testing.assert_allclose(after, np.broadcast_to(before.mean(0),
                                                       after.shape),
                                rtol=1e-6, atol=1e-6)
+
+
+def test_hfl_cli_prints_the_per_step_loop_losses(monkeypatch, capsys):
+    """``main()``'s ``--hfl`` loop is ``run_hfl_rounds``: the losses it
+    prints are those of local steps with a sync after every
+    ``--sync-every`` of them and a last short round without one."""
+    argv = ["--arch", "mamba2-1.3b", "--reduced", "--hfl", "--clusters", "2",
+            "--steps", "5", "--sync-every", "2", "--batch", "2", "--seq", "32",
+            "--log-every", "1"]
+    monkeypatch.setattr(sys, "argv", ["train"] + argv)
+    monkeypatch.setattr(train, "use_compile_cache", lambda: None)
+    train.main()
+    printed = [line.rsplit(" (", 1)[0] for line in
+               capsys.readouterr().out.splitlines() if line.startswith("step")]
+
+    args = train.parse_args(argv)
+    cfg = train.build_cfg(args)
+    mesh = train.hfl_mesh(args.clusters)
+    init, local, place_batch = train.hfl_programs(
+        cfg, AdamWConfig(lr=args.lr, warmup_steps=args.warmup), mesh,
+        args.clusters)
+    sync = train.hfl_sync(cfg, mesh)
+    state = init(jax.random.PRNGKey(args.seed))
+    streams = train.cluster_streams(cfg, args)
+    expected = []
+    for i in range(args.steps):
+        state, m = local(state, place_batch([next(s) for s in streams]))
+        if (i + 1) % 2 == 0:
+            state = sync(state)
+        expected.append(f"step {i:4d} loss/cluster="
+                        f"{[round(float(x), 4) for x in m['loss']]}")
+    assert printed == expected

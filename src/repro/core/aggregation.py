@@ -139,19 +139,33 @@ def stack_rows(rows):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
 
 
-@jax.jit
-def apply_buffered_deltas(global_params, rows, weights):
-    """FedBuff flush as one program: global += mean_k of
-    weights[k] * (new_k - base_k). ``rows`` is the buffer's sequence of
-    ``(new_k, base_k)`` model pairs, stacked inside; one trace per buffer
-    length."""
-    stacked_new, stacked_base = stack_rows(rows)
-
+def _add_mean_delta(global_params, stacked_new, stacked_base, weights):
+    """global += mean_k of weights[k] * (new_k - base_k), over stacked
+    (D, ...) new and base models."""
     def upd(g, n, b):
         wb = weights.reshape((-1,) + (1,) * (n.ndim - 1))
         d = (wb * (n.astype(jnp.float32) - b.astype(jnp.float32))).mean(0)
         return (g.astype(jnp.float32) + d).astype(g.dtype)
     return jax.tree.map(upd, global_params, stacked_new, stacked_base)
+
+
+@jax.jit
+def apply_buffered_deltas(global_params, rows, weights):
+    """Buffered stale deltas as one program: global += mean_k of
+    weights[k] * (new_k - base_k). ``rows`` is a sequence of
+    ``(new_k, base_k)`` model pairs, stacked inside; one trace per
+    length. The straggler fold's form."""
+    return _add_mean_delta(global_params, *stack_rows(rows), weights)
+
+
+@jax.jit
+def apply_stacked_deltas(global_params, stacked_new, bases, weights):
+    """``apply_buffered_deltas`` with the new models already stacked
+    (D, ...), as the buffer's training program returns them, and the
+    ``bases`` a sequence of D models, stacked inside. The FedBuff flush's
+    form: the same math in one program, with no per-row unstacking."""
+    return _add_mean_delta(global_params, stacked_new, stack_rows(bases),
+                           weights)
 
 
 @partial(jax.jit, static_argnames=("n_segments",))

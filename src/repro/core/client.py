@@ -7,7 +7,8 @@ stacked clients is one compiled dispatch. Its cache is keyed on
 (model, batch_size, mu_on, cohort width, data shapes) ONLY — epochs, lr, mu
 and the params themselves are dynamic, so a padded fixed-width cohort
 compiles exactly once per configuration no matter how per-round eligibility
-fluctuates. ``train_cache_sizes`` exposes the jit cache counters so tests
+fluctuates. ``local_sgd_buffer`` trains a FedBuff buffer in one dispatch
+at its flush. ``train_cache_sizes`` exposes the jit cache counters so tests
 and benchmarks can assert the compile-once invariant.
 """
 from __future__ import annotations
@@ -91,12 +92,49 @@ def local_sgd_clients(model, stacked_params, xs, ys, keys, epochs, batch_size,
                             batch_size, lr, mu, mu_on, global_params)
 
 
+@partial(jax.jit, static_argnames=("model", "batch_size"))
+def local_sgd_buffer(model, bases, x, y, idx, key, epochs, batch_size, lr,
+                     mu):
+    """Train a FedBuff buffer of D returns in one dispatch.
+
+    Entry i trains client ``idx[i]`` on ``x[idx[i]]``, ``y[idx[i]]`` (the
+    whole fleet's data, gathered inside) for ``epochs[i]`` epochs from
+    ``bases[i]``, the version it picked up, with FedProx anchored at that
+    base: what ``local_sgd(..., mu_on=True, global_params=bases[i])`` does
+    for one return. ``bases`` is a sequence of D models, stacked inside.
+    A sequential scan, not a vmap, so every entry keeps the single-client
+    program and its own epoch count. Entry i's subkey comes from the
+    ``key, sub = split(key)`` chain taken i + 1 times, the keys D
+    per-return splits give. Returns (trained models stacked (D, ...), the
+    advanced key)."""
+    stacked = jax.tree.map(lambda *leaves: jnp.stack(leaves), *bases)
+
+    # One dynamic slice per entry, before the scan. The TPU compiler
+    # lowers ``x[idx]`` as a gather that copies the whole fleet's data,
+    # and hoists a slice taken inside the scan behind a bf16 cast of it.
+    def take(a):
+        return jnp.stack([jax.lax.dynamic_index_in_dim(a, idx[i], 0, False)
+                          for i in range(idx.shape[0])])
+
+    def entry(key, args):
+        base, xk, yk, e = args
+        key, sub = jax.random.split(key)
+        return key, _local_sgd(model, base, xk, yk, sub, e, batch_size, lr,
+                               mu, True, base)
+
+    key, trained = jax.lax.scan(entry, key,
+                                (stacked, take(x), take(y), epochs))
+    return trained, key
+
+
 def train_cache_sizes() -> dict:
     """Jit-cache entry counts for the training hot paths (trace counters)."""
     return {"local_sgd": local_sgd._cache_size(),
-            "local_sgd_clients": _local_sgd_batch._cache_size()}
+            "local_sgd_clients": _local_sgd_batch._cache_size(),
+            "local_sgd_buffer": local_sgd_buffer._cache_size()}
 
 
 def clear_train_caches() -> None:
     local_sgd._clear_cache()
     _local_sgd_batch._clear_cache()
+    local_sgd_buffer._clear_cache()

@@ -24,11 +24,7 @@ from __future__ import annotations
 import heapq
 from typing import Dict, Optional
 
-import jax
 import numpy as np
-
-from repro.core.client import local_sgd
-from repro.core.quantize import quantize_roundtrip
 
 
 def run_loop(algo, t0: float = 0.0, t_end: Optional[float] = None,
@@ -64,7 +60,10 @@ def run_fedbuff_loop(algo, t0: float = 0.0, t_end: Optional[float] = None,
     ``(return_time, sat)`` tuples (ties break on the satellite index by
     tuple comparison — the ordering the EventQueue port must preserve),
     with the PR 5-7 energy deferral, fault re-scheduling, and payload-
-    fault semantics exactly as shipped."""
+    fault semantics exactly as shipped. A return queues the same pending
+    entry as ``FedBuffSat.run`` and the buffer trains and folds in the
+    engine's own ``_flush_buffer``, so the parity gate stays about the
+    clock."""
     cfg, plan = algo.cfg, algo.plan
     t_end = t_end if t_end is not None else plan.horizon_s
     max_rounds = max_rounds or cfg.max_rounds
@@ -135,7 +134,6 @@ def run_fedbuff_loop(algo, t0: float = 0.0, t_end: Optional[float] = None,
     idle_acc, comm_acc, train_acc, n_ev = 0.0, 0.0, 0.0, 0
     energy_acc, skip_acc = 0.0, 0
     fault_acc, drop_acc, rebill_acc = 0, 0, 0.0
-    corr_acc = 0
     comm_by: Dict[int, float] = {}
     while heap and r < max_rounds:
         t_ret, k = heapq.heappop(heap)
@@ -147,21 +145,9 @@ def run_fedbuff_loop(algo, t0: float = 0.0, t_end: Optional[float] = None,
                  and algo.faults.reset_in(k, pickup_t.get(k, t0), t_ret))
         n_drops = 0
         if not wiped:
-            algo.key, sub = jax.random.split(algo.key)
-            trained = local_sgd(cfg.model, client_params[k],
-                                algo.ds.x[k], algo.ds.y[k], sub,
-                                epochs_of[k], cfg.batch_size, cfg.lr,
-                                cfg.prox_mu, True, client_params[k])
-            if cfg.quant_bits:
-                trained = quantize_roundtrip(trained, cfg.quant_bits)
-            if algo.faults is not None \
-                    and algo.faults.cfg.has_payload_faults:
-                trained, bad = algo._payload_fault_model(
-                    k, trained, t_ret, client_params[k])
-                corr_acc += int(bad)
             stale = r - pickup_round[k]
             wgt = (1.0 + stale) ** (-cfg.staleness_exponent)
-            buf.append((trained, client_params[k], wgt))
+            buf.append((k, client_params[k], epochs_of[k], wgt, t_ret))
             comm_acc += t_up + t_down
             comm_by[k] = comm_by.get(k, 0.0) + t_up + t_down
             train_acc += train_s
@@ -243,13 +229,12 @@ def run_fedbuff_loop(algo, t0: float = 0.0, t_end: Optional[float] = None,
                 energy_wh=energy_acc, skipped_low_power=skip_acc,
                 comm_s_by_sat=comm_by, skipped_faulted=fault_acc,
                 dropped_contacts=drop_acc, retransmit_bytes=rebill_acc,
-                corrupted_updates=corr_acc,
+                corrupted_updates=algo._last_flush_corrupted,
                 clipped_updates=algo._last_flush_clipped))
             t_round_start = t_ret
             idle_acc = comm_acc = train_acc = 0.0
             energy_acc, skip_acc = 0.0, 0
             fault_acc, drop_acc, rebill_acc = 0, 0, 0.0
-            corr_acc = 0
             comm_by = {}
             n_ev = 0
             r += 1

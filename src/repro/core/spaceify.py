@@ -88,11 +88,12 @@ import numpy as np
 
 from repro import obs
 from repro.core.aggregation import (apply_buffered_deltas,
+                                    apply_stacked_deltas,
                                     make_robust_aggregator,
                                     quantized_weighted_average,
                                     robust_apply_buffered_deltas,
                                     stack_rows, weighted_average)
-from repro.core.client import local_sgd, local_sgd_clients
+from repro.core.client import local_sgd_buffer, local_sgd_clients
 from repro.core.contact_plan import ContactPlan
 from repro.core.policy import PolicyInputs, resolve_policy, select_top
 from repro.core.quantize import (quantize_roundtrip,
@@ -806,16 +807,29 @@ class SpaceifiedFL:
         ``rows`` of ``(new_k, base_k)`` models: the weighted mean in one
         program (``apply_buffered_deltas``), or the robust estimator when
         one is configured. Returns the estimator's attenuated row count
-        (0 for the mean). The FedBuff flush and the straggler fold."""
+        (0 for the mean). The straggler fold."""
         wgts = np.asarray(weights, np.float32)
         if self.aggregator is None:
             self.global_params = apply_buffered_deltas(
                 self.global_params, rows, wgts)
             return 0
-        # robust: the estimator sees the staleness-weighted deltas (zero
+        return self._robust_deltas(*stack_rows(rows), wgts)
+
+    def _apply_stacked_deltas(self, stacked_new, bases, weights) -> int:
+        """``_apply_deltas`` over the stacked (D, ...) new models and the
+        sequence of their ``bases`` (``apply_stacked_deltas``). The
+        FedBuff flush."""
+        wgts = np.asarray(weights, np.float32)
+        if self.aggregator is None:
+            self.global_params = apply_stacked_deltas(
+                self.global_params, stacked_new, bases, wgts)
+            return 0
+        return self._robust_deltas(stacked_new, stack_rows(bases), wgts)
+
+    def _robust_deltas(self, stacked_new, stacked_base, wgts) -> int:
+        # the estimator sees the staleness-weighted deltas (zero
         # reference), so a poisoned or corrupted row is attenuated
         # before it touches the global
-        stacked_new, stacked_base = stack_rows(rows)
         self.global_params, n_att = robust_apply_buffered_deltas(
             self.global_params, stacked_new, stacked_base, wgts,
             self.aggregator, mode=self.cfg.quant_kernel)
@@ -837,8 +851,9 @@ class SpaceifiedFL:
         A compromised satellite (``faults.poison``) submits the
         model-replacement payload ``(1+s)*ref - s*trained`` — its honest
         delta reversed and amplified by ``s`` — crafted from the
-        ``reference`` it trained against, so poisoning takes precedence
-        over the SEU draw. Otherwise a counter-based SEU draw
+        ``reference`` it trained against (one model: the broadcast global,
+        or the base a FedBuff return picked up), so poisoning takes
+        precedence over the SEU draw. Otherwise a counter-based SEU draw
         (``corruption_at``) may flip the row's sign, blow up its scale,
         or add large-magnitude seeded noise. Only this row of the tree is
         touched: corruption must never perturb the other cohort members.
@@ -867,34 +882,6 @@ class SpaceifiedFL:
                     rng.standard_normal(p.shape[1:]) * factor, p.dtype)),
                 params)
         return params, True
-
-    def _payload_fault_model(self, k: int, params, t_deliver: float,
-                             reference):
-        """Unstacked sibling of ``_corrupt_row`` for the async engine:
-        apply ``k``'s payload fault (if any) to a single delivered model.
-        Returns (params, was_bad)."""
-        fc = self.faults.cfg
-        if fc.poison is not None and fc.poison.compromised(k):
-            s = fc.poison.scale
-            out = jax.tree.map(
-                lambda p, g: ((1.0 + s) * g.astype(jnp.float32)
-                              - s * p.astype(jnp.float32)).astype(p.dtype),
-                params, reference)
-            return out, True
-        draw = self.faults.corruption_at(k, t_deliver)
-        if draw is None:
-            return params, False
-        mode, factor, noise_seed = draw
-        if mode == "sign_flip":
-            out = jax.tree.map(lambda p: -p, params)
-        elif mode == "scale":
-            out = jax.tree.map(lambda p: p * factor, params)
-        else:
-            rng = np.random.default_rng(noise_seed)
-            out = jax.tree.map(
-                lambda p: p + jnp.asarray(
-                    rng.standard_normal(p.shape) * factor, p.dtype), params)
-        return out, True
 
     def _apply_payload_faults(self, trained, sel, delivered, t_deliver):
         """Corrupt/poison the *delivered* rows of a trained cohort at
@@ -1171,8 +1158,10 @@ class FedBuffSat(SpaceifiedFL):
     """Algorithm 4: asynchronous buffered aggregation. Clients train
     continuously between ground contacts (near-zero idle, paper Fig. 5c);
     the server folds in updates with staleness discounting and completes a
-    "round" when the buffer reaches D updates. The flush is one stacked
-    delta reduction (``apply_buffered_deltas``) over the whole buffer.
+    "round" when the buffer reaches D updates. A return launches no
+    device work: it queues a pending entry, and the flush trains the
+    whole buffer in one program (``local_sgd_buffer``) and folds it in
+    one stacked delta reduction (``apply_stacked_deltas``).
 
     This is the discrete-event core's first real consumer: the pending
     deliveries live on a deterministic ``EventQueue`` of CLIENT_RETURN
@@ -1185,20 +1174,60 @@ class FedBuffSat(SpaceifiedFL):
 
     name = "fedbuff"
 
-    # robust-estimator row count of the last buffer flush (read by the
-    # retained ref loop so both loops share the flush math)
+    # robust-estimator row count and corrupted-payload count of the last
+    # buffer flush (read by both loops, which share the flush)
     _last_flush_clipped = 0
+    _last_flush_corrupted = 0
 
     def _flush_buffer(self, buf) -> None:
-        """Fold a full buffer into the global model: one stacked delta
-        reduction, routed through the robust estimator when
-        ``FLConfig.aggregator`` is set. Shared by the event-driven
-        ``run()`` and ``round_loop_ref.run_fedbuff_loop`` — like
-        ``round_engine_ref`` shares ``weighted_average``, sharing the
-        flush keeps the bitwise-parity gate about the *clock*, not the
-        reduction tree. Sets ``self._last_flush_clipped``."""
-        self._last_flush_clipped = self._apply_deltas(
-            [b[:2] for b in buf], [b[2] for b in buf])
+        """Train a full buffer and fold it into the global model.
+
+        ``buf`` holds one pending entry per return,
+        ``(k, base, epochs, weight, t_ret)``: the satellite, the version
+        it picked up, its epoch budget, its staleness weight and its
+        return time. One program trains every entry
+        (``local_sgd_buffer``), drawing each entry's key from
+        ``self.key`` in return order, as a split at each return would.
+        The trained content never depends on the return time, so the
+        training can wait for the flush, the only place that reads it.
+        What crosses the radio then happens to the stacked rows in
+        return order: the QuAFL round trip (``quant_bits``), then the
+        payload faults at each return's time. One stacked delta
+        reduction folds the buffer, through the robust estimator when
+        ``FLConfig.aggregator`` is set.
+
+        Shared by the event-driven ``run()`` and
+        ``round_loop_ref.run_fedbuff_loop``: like ``round_engine_ref``
+        shares ``weighted_average``, sharing the flush keeps the
+        bitwise-parity gate about the *clock*, not the training or the
+        reduction tree. A partial buffer left when a run ends is never
+        trained, so ``self.key`` then ends fewer splits on than a
+        per-return split would leave it; nothing reads it after
+        ``run()``. Sets ``self._last_flush_clipped`` and
+        ``self._last_flush_corrupted``."""
+        cfg = self.cfg
+        sats = np.array([b[0] for b in buf], np.int32)
+        bases = tuple(b[1] for b in buf)
+        with obs.span("fl.train"):
+            obs.count("fedbuff_buffered_trains", len(buf))
+            trained, self.key = local_sgd_buffer(
+                cfg.model, bases, self.ds.x, self.ds.y, sats, self.key,
+                np.array([b[2] for b in buf], np.int32), cfg.batch_size,
+                cfg.lr, cfg.prox_mu)
+        with obs.span("fl.aggregate"):
+            if cfg.quant_bits:          # the returned models cross the radio
+                trained = quantize_roundtrip_stacked(trained, cfg.quant_bits)
+            n_corr = 0
+            if self.faults is not None and self.faults.cfg.has_payload_faults:
+                # a payload may be corrupted or poisoned in flight; the
+                # reference is the version the client trained from
+                for i, (k, base, _, _, t_ret) in enumerate(buf):
+                    trained, bad = self._corrupt_row(trained, i, int(k),
+                                                     t_ret, base)
+                    n_corr += int(bad)
+            self._last_flush_corrupted = n_corr
+            self._last_flush_clipped = self._apply_stacked_deltas(
+                trained, bases, [b[3] for b in buf])
 
     @obs.traced_run
     def run(self, t0: float = 0.0, t_end: Optional[float] = None,
@@ -1320,7 +1349,7 @@ class FedBuffSat(SpaceifiedFL):
         idle_acc, comm_acc, train_acc, n_ev = 0.0, 0.0, 0.0, 0
         energy_acc, skip_acc = 0.0, 0
         fault_acc, drop_acc, rebill_acc = 0, 0, 0.0
-        corr_acc, rex_acc, def_acc = 0, rex_seed, def_seed
+        rex_acc, def_acc = rex_seed, def_seed
         comm_by: Dict[int, float] = {}
         while queue and r < max_rounds:
             ev = queue.pop()
@@ -1344,27 +1373,11 @@ class FedBuffSat(SpaceifiedFL):
                                                   t_ret))
                 n_drops = 0
                 if not wiped:
-                    with obs.span("fl.train"):
-                        self.key, sub = jax.random.split(self.key)
-                        trained = local_sgd(
-                            cfg.model, client_params[k], self.ds.x[k],
-                            self.ds.y[k], sub, epochs_of[k], cfg.batch_size,
-                            cfg.lr, cfg.prox_mu, True, client_params[k])
-                        if cfg.quant_bits:  # the returned model crosses
-                            trained = quantize_roundtrip(  # the radio
-                                trained, cfg.quant_bits)
-                    if self.faults is not None \
-                            and self.faults.cfg.has_payload_faults:
-                        # the payload may be corrupted/poisoned in flight:
-                        # the delivery still bills its bytes, the buffered
-                        # weights are what went bad. Reference = the pickup
-                        # version the client trained from.
-                        trained, bad = self._payload_fault_model(
-                            k, trained, t_ret, client_params[k])
-                        corr_acc += int(bad)
+                    # queued, not trained: the flush trains the buffer
                     stale = r - pickup_round[k]
                     wgt = (1.0 + stale) ** (-cfg.staleness_exponent)
-                    buf.append((trained, client_params[k], wgt))
+                    buf.append((k, client_params[k], epochs_of[k], wgt,
+                                t_ret))
                     comm_acc += t_up + t_down
                     comm_by[k] = comm_by.get(k, 0.0) + t_up + t_down
                     train_acc += train_s
@@ -1489,9 +1502,7 @@ class FedBuffSat(SpaceifiedFL):
 
                 if len(buf) >= cfg.buffer_size:
                     st.add(ROUND_BARRIER)
-                    with obs.span("fl.aggregate"):
-                        self._flush_buffer(buf)
-                    n_clip = self._last_flush_clipped
+                    self._flush_buffer(buf)
                     buf = []
                     acc = self.evaluate() if r % cfg.eval_every == 0 else \
                         (self.records[-1].accuracy if self.records else 0.0)
@@ -1506,7 +1517,8 @@ class FedBuffSat(SpaceifiedFL):
                         energy_wh=energy_acc, skipped_low_power=skip_acc,
                         comm_s_by_sat=comm_by, skipped_faulted=fault_acc,
                         dropped_contacts=drop_acc, retransmit_bytes=rebill_acc,
-                        corrupted_updates=corr_acc, clipped_updates=n_clip,
+                        corrupted_updates=self._last_flush_corrupted,
+                        clipped_updates=self._last_flush_clipped,
                         retries_exhausted=rex_acc,
                         storm_events=self._storms_in(t_round_start, t_ret),
                         policy_deferred=def_acc,
@@ -1516,7 +1528,7 @@ class FedBuffSat(SpaceifiedFL):
                     idle_acc = comm_acc = train_acc = 0.0
                     energy_acc, skip_acc = 0.0, 0
                     fault_acc, drop_acc, rebill_acc = 0, 0, 0.0
-                    corr_acc, rex_acc, def_acc = 0, 0, 0
+                    rex_acc, def_acc = 0, 0
                     comm_by = {}
                     n_ev = 0
                     r += 1

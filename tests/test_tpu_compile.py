@@ -14,7 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.aggregation import quantized_weighted_average
-from repro.core.client import _local_sgd_batch
+from repro.core.client import _local_sgd_batch, local_sgd_buffer
 from repro.data.synthetic import DATASETS
 from repro.kernels.quant_agg import TILE_LANES, TILE_SUB, quant_agg_stacked_tiles
 from repro.kernels.ssd_scan import ssd_chunk_pallas
@@ -23,6 +23,8 @@ from repro.kernels.trimmed_agg import trimmed_agg_tiles
 from repro.models.small import init_cnn
 
 COHORT = 50          # the 10x10 Walker-star's clients_per_round in chip_smoke
+FLEET = 100          # the 10x10 Walker-star's satellites
+BUFFER = 5           # FedBuff's buffer in the benchmark's cell
 N_TILES = 64
 
 
@@ -115,6 +117,27 @@ def test_cohort_trainer_compiles(one_chip):
         _sds(one_chip, (COHORT,), jnp.int32),
         32, _sds(one_chip, (), jnp.float32), _sds(one_chip, (), jnp.float32),
         False, None).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_fedbuff_buffer_trainer_compiles(one_chip):
+    """FedBuff's buffer program at the benchmark cell's shapes (5 returns,
+    100 satellites of 216 EuroSAT images, batch 32) fits one chip."""
+    h, w, c, n_classes = DATASETS["eurosat"]
+    n_per_client = 216
+    params = jax.eval_shape(lambda k: init_cnn(k, (h, w, c), n_classes),
+                            jax.random.PRNGKey(0))
+    base = jax.tree.map(lambda p: _sds(one_chip, p.shape, p.dtype), params)
+    compiled = local_sgd_buffer.lower(
+        "cnn", (base,) * BUFFER,
+        _sds(one_chip, (FLEET, n_per_client, h, w, c), jnp.float32),
+        _sds(one_chip, (FLEET, n_per_client), jnp.int32),
+        _sds(one_chip, (BUFFER,), jnp.int32),
+        _sds(one_chip, (2,), jnp.uint32),
+        _sds(one_chip, (BUFFER,), jnp.int32),
+        32, _sds(one_chip, (), jnp.float32),
+        _sds(one_chip, (), jnp.float32)).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
 
